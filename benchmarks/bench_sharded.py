@@ -23,16 +23,17 @@ Backs the sharded-store PR's acceptance bar on a >= 100k-item store:
    ``merged_metrics()``; merged counts must equal the routed totals.
 
 Results land in ``BENCH_sharded.json`` at the repo root (CSV rows remain
-the stdout contract).  The mesh is CPU-hosted: ``XLA_FLAGS`` below forces
-8 host devices, so the bench runs identically in CI and on a laptop.
+the stdout contract).  Under ``JAX_PLATFORMS=cpu`` the mesh is CPU-hosted:
+``XLA_FLAGS`` below forces 8 host devices, so the bench runs identically in
+CI and on a laptop.  Otherwise the shards take the accelerator's devices.
 """
 from __future__ import annotations
 
 import os
 
 # must precede the first jax import anywhere in the process
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import json
 import pathlib

@@ -16,6 +16,10 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     fast = not args.full
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (
         bench_ablation,
         bench_cost,

@@ -360,24 +360,6 @@ def _observe_scalar(
         h.grid.add(wan_link)
 
 
-# jax + kernels are imported lazily on the first fast-path call: the numpy
-# router must keep working (and importing fast) when jax is unavailable
-_KOPS = None  # geolint: allow[GL001]
-_KOPS_FAILED = False  # geolint: allow[GL001]
-
-
-def _get_kops():
-    global _KOPS, _KOPS_FAILED
-    if _KOPS is None and not _KOPS_FAILED:
-        try:
-            from ..kernels import autotune, ops
-
-            _KOPS = (ops, autotune)
-        except Exception:  # pragma: no cover - jax-less deployment
-            _KOPS_FAILED = True
-    return _KOPS
-
-
 def _fast_eligible(
     fast: Optional[bool], config: RouteFastConfig, R: int, D: int, kmax: int,
     n_layers: int,
@@ -391,7 +373,7 @@ def _fast_eligible(
             return False
         if kmax > config.max_kmax or R * kmax > config.max_cells:
             return False
-    return _get_kops() is not None
+    return True
 
 
 # per-LayeredGraph device copies of the expansion constants (layer
@@ -405,15 +387,13 @@ _FAST_ENV_CACHE: Dict[int, Tuple[LayeredGraph, tuple]] = {}  # geolint: allow[GL
 
 def reset_routing_caches() -> None:
     """Reset every module-level routing cache/singleton: the per-layer tag
-    intern table, the fast-path config, the lazy kernels import memo and the
-    per-graph device-array cache.  Test isolation hook — everything here
+    intern table, the fast-path config and the per-graph device-array
+    cache.  Test isolation hook — everything here
     rebuilds lazily on next use."""
-    global _FAST_CONFIG, _KOPS, _KOPS_FAILED
+    global _FAST_CONFIG
     _LAYER_TAGS.clear()
     _FAST_ENV_CACHE.clear()
     _FAST_CONFIG = RouteFastConfig()
-    _KOPS = None
-    _KOPS_FAILED = False
 
 
 def _fast_env_arrays(lg: LayeredGraph) -> tuple:
@@ -458,7 +438,10 @@ def _route_batch_fast(
     exactly on the host by the shared epilogue, so results are bit-identical
     to the numpy path.
     """
-    ops, autotune = _get_kops()
+    # jax + kernels load on the first fast-path call, so the numpy router
+    # imports fast
+    from ..kernels import autotune, ops
+
     R = len(lens)
     K = delta_all.shape[0]
     D = delta_all.shape[1]

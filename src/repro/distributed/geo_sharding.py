@@ -69,14 +69,15 @@ def mesh_env(n_shards: int, shards_per_pod: Optional[int] = None) -> GeoEnvironm
 
 
 def mesh_devices(n_shards: int) -> List:
-    """One jax device per store shard, cycling when the runtime exposes
-    fewer than ``n_shards``.
+    """One jax device per store shard.
 
-    Tests/CI force an N-device CPU mesh with
+    On an accelerator backend every shard gets a device of its own, and a
+    runtime exposing fewer than ``n_shards`` devices is an error.  The CPU
+    host mesh cycles instead: tests/CI force N host devices with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before jax
-    initializes).  Without it every shard lands on device 0 — the
-    single-process fallback: functionally identical, payload transfers
-    degenerate to same-device copies, nothing runs in parallel.
+    initializes), and without it every shard lands on device 0 —
+    functionally identical, payload transfers degenerate to same-device
+    copies, nothing runs in parallel.
 
     jax imports lazily so the placement/routing planners in this module stay
     importable without an accelerator runtime.
@@ -84,6 +85,11 @@ def mesh_devices(n_shards: int) -> List:
     import jax
 
     devs = jax.devices()
+    if len(devs) < n_shards and devs[0].platform != "cpu":
+        raise RuntimeError(
+            f"{n_shards} shards need {n_shards} {devs[0].platform} devices; "
+            f"the runtime exposes {len(devs)}"
+        )
     return [devs[i % len(devs)] for i in range(n_shards)]
 
 

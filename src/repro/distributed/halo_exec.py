@@ -33,18 +33,6 @@ from ..core.graph import Graph
 __all__ = ["HaloProgram", "build_halo_program", "run_message_passing", "exchange_stats"]
 
 
-def _resolve_shard_map():
-    """shard_map moved from jax.experimental to the jax namespace (and the
-    replication-check kwarg was renamed check_rep -> check_vma) across JAX
-    releases; resolve whichever this install provides."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm, "check_vma"
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp, "check_rep"
-
-
 @dataclasses.dataclass
 class HaloProgram:
     """Static plan for shard_map halo message passing over a partition.
@@ -183,14 +171,12 @@ def run_message_passing(
         )
         return x + jnp.tanh(agg / jnp.maximum(deg, 1.0)[:, None])
 
-    shard_map_fn, check_kw = _resolve_shard_map()
-
     @partial(
-        shard_map_fn,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=P(axis),
-        **{check_kw: False},
+        check_vma=False,
     )
     def run(x, send_idx, send_mask, e_src, e_dst, e_mask):
         x, send_idx = x[0], send_idx[0]

@@ -4,9 +4,10 @@ One :class:`~repro.core.store.GeoGraphStore` becomes per-DC **store shards**
 laid over a jax device mesh (the mesh-as-geo mapping of
 :mod:`repro.distributed.geo_sharding`: shards = DCs, ICI/DCN = WAN tiers).
 Tests and CI force an N-device CPU mesh with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``; with fewer devices
-than shards the mapping cycles (single-process fallback — identical results,
-no parallel payload plane).
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``; on the CPU host
+mesh, fewer devices than shards cycle (single-process fallback — identical
+results, no parallel payload plane), while an accelerator needs one device
+per shard.
 
 Three planes, split by what must stay authoritative where:
 

@@ -69,14 +69,11 @@ class Autotuner:
     @staticmethod
     def device_kind() -> str:
         """``backend:device_kind`` of the default jax device (e.g.
-        ``cpu:cpu`` or ``tpu:TPU v5e``); ``unknown`` when jax is absent."""
-        try:
-            import jax
+        ``cpu:cpu`` or ``tpu:TPU v5 lite``)."""
+        import jax
 
-            dev = jax.devices()[0]
-            return f"{jax.default_backend()}:{getattr(dev, 'device_kind', '?')}"
-        except Exception:  # pragma: no cover - no backend at all
-            return "unknown"
+        dev = jax.devices()[0]
+        return f"{jax.default_backend()}:{dev.device_kind}"
 
     # -------------------------------------------------------------- lookups
     def lookup(

@@ -73,7 +73,7 @@ def embedding_bag(
     mode: str = "sum",
     block_b: int = 128,
     block_v: int = 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     v, d = table.shape
     b, l = indices.shape

@@ -106,7 +106,7 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape

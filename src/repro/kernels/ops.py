@@ -229,7 +229,7 @@ def dhd_step(
     if use_kernel:
         out = dhd_ell_step(
             heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta,
-            block_n=min(block_n, heat.shape[0]), interpret=not on_tpu(),
+            block_n=block_n, interpret=not on_tpu(),
         )
         _obs_dispatch("dhd_step", "kernel", t0)
         return out
@@ -277,7 +277,7 @@ def dhd_step_batch(
     if use_kernel:
         out = dhd_ell_step_batch(
             heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta,
-            block_n=min(block_n, heat.shape[1]), interpret=not on_tpu(),
+            block_n=block_n, interpret=not on_tpu(),
         )
         _obs_dispatch("dhd_step_batch", "kernel", t0)
         return out
@@ -395,7 +395,7 @@ def diffuse_batch(
         h = _diffuse_ell_loop(
             jnp.asarray(cols), jnp.asarray(vals), h0, seeds_j,
             n_steps=n_steps, alpha=p.alpha, gamma=p.gamma, beta=p.beta,
-            half_life=half_life, block_n=min(block_n, n_nodes),
+            half_life=half_life, block_n=block_n,
             interpret=not on_tpu(),
         )
         _obs_dispatch("diffuse_batch", "kernel", t0)

@@ -43,6 +43,9 @@ __all__ = ["route_expand", "STATS_LANES", "STAT_MISS_BASE"]
 # layer l (l = 0 .. n_layers)
 STATS_LANES = 128
 STAT_MISS_BASE = 8
+# cells of one [block_r, k_pad] 4-byte block (1 MiB): bits, sizes and served
+# double-buffered come to 6 MiB
+_TILE_CELLS = 1 << 18
 
 
 def _expand_kernel(
@@ -65,12 +68,11 @@ def _expand_kernel(
     sizes = sizes_ref[...]
     lens = lens_ref[...]  # [block_r, 1]
     origin = origin_ref[...]  # [block_r, 1]
-    allowed = allowed_ref[...]
     origin_oh = origin_oh_ref[...]
     rtt = rtt_ref[...]
     ibw = ibw_ref[...]
     block_r, k_pad = bits.shape
-    d_pad = allowed.shape[2]
+    d_pad = allowed_ref.shape[2]
     f32 = sizes.dtype
 
     iota_k = jax.lax.broadcasted_iota(jnp.int32, (block_r, k_pad), 1)
@@ -79,7 +81,9 @@ def _expand_kernel(
 
     valid = iota_k < lens
     local = valid & (((bits >> origin) & 1) > 0)
-    missing0 = valid & jnp.logical_not(local)
+    # loop-carried masks are int32 0/1: Mosaic cannot carry a bool vector
+    # through the while_loop
+    missing0 = (valid & jnp.logical_not(local)).astype(jnp.int32)
     # field-word coverage (see ref.route_expand_ref): for item tiles <= 512
     # wide, spread bit d of each item into a 10-bit field, 3 DCs per int32
     # word — one reduction per word yields 3 exact per-DC popcounts
@@ -92,7 +96,7 @@ def _expand_kernel(
                 acc = acc + (((bits >> d) & 1) << (10 * j))
             words.append(acc)
 
-    def _coverage(missing):
+    def _coverage(missing):  # missing: bool [block_r, Kp]
         cover = jnp.zeros((block_r, d_pad), f32)
         if use_fields:
             for w, word in enumerate(words):
@@ -116,11 +120,12 @@ def _expand_kernel(
 
     def cond(c):
         _, missing, layer, _, _, it = c
-        return (layer < n_layers) & missing.any() & (it < max_iters)
+        return (layer < n_layers) & (missing.max() > 0) & (it < max_iters)
 
     def body(c):
         served, missing, layer, layers_used, miss_stats, it = c
-        a_l = jax.lax.dynamic_index_in_dim(allowed, layer, axis=1, keepdims=False)
+        missing = missing > 0
+        a_l = allowed_ref[:, layer, :]  # [block_r, Dp], read per layer
         layers_used = jnp.where(
             missing.any(axis=1, keepdims=True)
             & (a_l.max(axis=1, keepdims=True) > 0),
@@ -146,7 +151,7 @@ def _expand_kernel(
         )
         return (
             jnp.where(hit, best, served),
-            new_missing,
+            new_missing.astype(jnp.int32),
             jnp.where(progressed, layer, layer + 1),
             layers_used,
             miss_stats,
@@ -216,7 +221,7 @@ def route_expand(
     ibw: jnp.ndarray,  # [D, D] f32 elementwise 1 / bandwidth matrix
     *,
     block_r: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, ...]:
     """Pallas route-expansion; same contract as ``ref.route_expand_ref``.
 
@@ -233,9 +238,12 @@ def route_expand(
     D = comp.shape[1]
     assert STAT_MISS_BASE + L + 1 <= STATS_LANES
     assert D <= STATS_LANES
+    k_pad = -(-max(K, 1) // 128) * 128
+    # wide item tiles get fewer request rows, so the double-buffered
+    # [block_r, k_pad] blocks stay inside the default scoped VMEM
+    block_r = min(block_r, max(8, _TILE_CELLS // k_pad // 8 * 8))
     block_r = max(8, min(block_r, -(-R // 8) * 8))
     r_pad = -(-R // block_r) * block_r
-    k_pad = -(-max(K, 1) // 128) * 128
     d_pad = -(-max(D, 1) // 8) * 8
 
     origin = origin.astype(jnp.int32)
