@@ -20,7 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..obs import get_registry
+from ..obs import Tracer, get_registry
 from .cost import PlacementState
 from .graph import Graph
 from .latency import GeoEnvironment
@@ -60,7 +60,7 @@ class _ObsHandles:
     instrument objects, so handles survive it)."""
 
     __slots__ = (
-        "requests", "wan", "lat", "grid", "kernel_time", "unresolved",
+        "requests", "wan", "lat", "grid", "unresolved",
         "layer_hits", "layer_time", "_reg",
     )
 
@@ -72,7 +72,6 @@ class _ObsHandles:
             "serving.request_latency_s", quantiles=(0.5, 0.99)
         )
         self.grid = reg.counter_grid("serving.wan_bytes_link", ("src", "dst"))
-        self.kernel_time = reg.counter_keyed("routing.kernel_time_s", ())
         self.unresolved = reg.counter_keyed("routing.unresolved_items", ())
         self.layer_hits: dict = {}
         self.layer_time: dict = {}
@@ -376,6 +375,10 @@ def _fast_eligible(
     return True
 
 
+# stands in for a caller's tracer when none is given: disabled, so every
+# span it opens is a no-op that records nothing
+_NO_TRACER = Tracer(enabled=False)
+
 # per-LayeredGraph device copies of the expansion constants (layer
 # components, RTT, 1/bandwidth): host->device conversion has a fixed ~70us
 # cost per array, which the per-batch fast path cannot afford for arrays
@@ -422,6 +425,8 @@ def _route_batch_fast(
     origin: np.ndarray,  # [R]
     reg,
     obs: bool,
+    tracer: Tracer,
+    prepare,  # the caller's open ``routing.prepare`` span, ended here
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Fused expansion for the whole batch on the kernels fast path.
 
@@ -436,7 +441,9 @@ def _route_batch_fast(
     cache is keyed on a handful of shapes across the batch mix.  Returns
     ``(served [K], layers_used [R])``; all byte/latency folds are recomputed
     exactly on the host by the shared epilogue, so results are bit-identical
-    to the numpy path.
+    to the numpy path.  The bit pack and tile fill close ``prepare``; the
+    device call runs in a ``routing.device`` span, the subset router in a
+    ``routing.expand`` one.
     """
     # jax + kernels load on the first fast-path call, so the numpy router
     # imports fast
@@ -445,7 +452,6 @@ def _route_batch_fast(
     R = len(lens)
     K = delta_all.shape[0]
     D = delta_all.shape[1]
-    t0 = time.perf_counter() if obs else 0.0
     kmax = int(lens.max())
     k_pad = autotune.shape_bucket(kmax, floor=8)
     r_pad = autotune.shape_bucket(R, floor=8)
@@ -471,9 +477,11 @@ def _route_batch_fast(
             else "ref"
         )
     if impl == "subsets" and D <= ops.SUBSET_MAX_DCS:
-        served, layers_used, miss_after = ops.route_expand_subsets(
-            bits_flat, req_id, R, origin, lg.comp_of_dc
-        )
+        prepare.end()
+        with tracer.span("routing.expand", track="store", impl="subsets"):
+            served, layers_used, miss_after = ops.route_expand_subsets(
+                bits_flat, req_id, R, origin, lg.comp_of_dc
+            )
     else:
         pos = np.arange(K, dtype=np.int64) - bounds[req_id]
         bits = np.zeros((r_pad, k_pad), np.int32)
@@ -485,15 +493,20 @@ def _route_batch_fast(
         origin_p = np.zeros(r_pad, np.int32)
         origin_p[:R] = origin
         comp, rtt, ibw = _fast_env_arrays(lg)
-        served_p, _, layers_used, miss_after, _, _ = ops.route_expand_batch(
-            bits, szp, lens_p, origin_p, comp, rtt, ibw,
-            use_kernel=impl == "kernel",
-            block_r=int(cfg.get("block_r", 128)),
-        )
+        use_kernel = impl == "kernel"
+        prepare.end()
+        with tracer.span(
+            "routing.device", track="store", impl="kernel" if use_kernel else "ref",
+            r_pad=r_pad, k_pad=k_pad,
+        ):
+            served_p, _, layers_used, miss_after, _, _ = ops.route_expand_batch(
+                bits, szp, lens_p, origin_p, comp, rtt, ibw,
+                use_kernel=use_kernel,
+                block_r=int(cfg.get("block_r", 128)),
+            )
         served = served_p[req_id, pos].astype(np.int64)
     if obs:
         h = _obs_handles(reg)
-        h.kernel_time.inc(time.perf_counter() - t0)
         # per-layer resolved counts from the kernel's missing-after-layer
         # columns (early-exited layers report 0 missing, which telescopes
         # to zero extra hits)
@@ -514,6 +527,7 @@ def route_online_batch(
     sizes: Optional[np.ndarray] = None,
     registry=None,
     fast: Optional[bool] = None,
+    tracer: Optional[Tracer] = None,
 ) -> List[RouteResult]:
     """Bottom-up expanding retrieval for a whole request batch at once.
 
@@ -538,12 +552,25 @@ def route_online_batch(
     ``registry`` routes serving/routing telemetry into an explicit
     :class:`~repro.obs.MetricsRegistry` (a shard's private registry);
     ``None`` falls back to the process default.
+
+    ``tracer`` times the stages as live spans on track ``store``:
+    ``routing.prepare`` (request arrays, item bytes — its child
+    ``routing.item_size`` — replica rows and, on the fast path, the packed
+    tiles), then ``routing.device`` (upload, kernel, fetch) or
+    ``routing.expand`` (a host expansion, tagged with its ``impl``), then
+    ``routing.epilogue``.  ``None`` opens no span.
     """
     env = lg.env
     R = len(requests)
     if R == 0:
         return []
     reg = registry if registry is not None else get_registry()
+    if tracer is None:
+        tracer = _NO_TRACER
+    prepare = tracer.span("routing.prepare", track="store", requests=R)
+    if sizes is None:
+        with tracer.span("routing.item_size", track="store"):
+            sizes = lg.g.item_size()
     if R == 1:
         # size-1 fast path: the flat batch machinery (request-id bookkeeping,
         # [R, D] coverage stacks) costs ~2x the scalar router at R == 1 and
@@ -553,18 +580,17 @@ def route_online_batch(
         # registries must account every request).
         items, origin_0 = requests[0]
         items = np.asarray(items)
-        if sizes is None:
-            sizes = lg.g.item_size()
-        t0 = time.perf_counter() if reg.enabled else 0.0
-        res = route_online(lg, state, items, int(origin_0), sizes=sizes)
-        if reg.enabled:
-            _observe_scalar(
-                reg, lg, res, items, int(origin_0), sizes,
-                time.perf_counter() - t0,
-            )
+        prepare.set_tags(items=len(items))
+        prepare.end()
+        with tracer.span("routing.expand", track="store", impl="scalar"):
+            t0 = time.perf_counter() if reg.enabled else 0.0
+            res = route_online(lg, state, items, int(origin_0), sizes=sizes)
+            if reg.enabled:
+                _observe_scalar(
+                    reg, lg, res, items, int(origin_0), sizes,
+                    time.perf_counter() - t0,
+                )
         return [res]
-    if sizes is None:
-        sizes = lg.g.item_size()
     arrs = [np.asarray(it) for it, _ in requests]
     lens = np.fromiter((a.shape[0] for a in arrs), dtype=np.int64, count=R)
     origin = np.fromiter((o for _, o in requests), dtype=np.int64, count=R)
@@ -575,6 +601,7 @@ def route_online_batch(
     )
     req_id = np.repeat(np.arange(R, dtype=np.int64), lens)
     K = len(items_all)
+    prepare.set_tags(items=K)
     D = env.n_dcs
     bounds = np.concatenate([[0], np.cumsum(lens)])
     # one gather each of the batch's replica rows and item bytes; every
@@ -592,87 +619,108 @@ def route_online_batch(
     if _fast_eligible(fast, _FAST_CONFIG, R, D, kmax, lg.n_layers):
         served, layers_used = _route_batch_fast(
             lg, delta_all, sz_all, req_id, bounds, lens, origin, reg, obs,
-        )
-        return _materialize_results(
-            env, sz_all, req_id, bounds, origin, served,
-            layers_used, R, D, reg, obs,
-        )
-
-    ar_K = np.arange(K)
-    ar_R = np.arange(R)
-    served = np.full(K, -1, dtype=np.int64)
-    layers_used = np.zeros(R, dtype=np.int64)
-    org_all = origin[req_id]
-    if (origin == origin[0]).all():
-        _expand_single_origin(
-            lg, delta_all, req_id, R, int(origin[0]), served, layers_used, reg, obs
+            tracer, prepare,
         )
     else:
-        # Layer_0: local items first
-        local = delta_all[ar_K, org_all]
-        served[local] = org_all[local]
+        prepare.end()
+        with tracer.span("routing.expand", track="store", impl="numpy"):
+            served = np.full(K, -1, dtype=np.int64)
+            layers_used = np.zeros(R, dtype=np.int64)
+            if (origin == origin[0]).all():
+                _expand_single_origin(
+                    lg, delta_all, req_id, R, int(origin[0]), served,
+                    layers_used, reg, obs,
+                )
+            else:
+                _expand_lockstep(
+                    lg, delta_all, req_id, R, origin, served, layers_used,
+                    reg, obs,
+                )
+    with tracer.span("routing.epilogue", track="store"):
+        return _materialize_results(
+            env, sz_all, req_id, bounds, origin, served, layers_used,
+            R, D, reg, obs,
+        )
 
+
+def _expand_lockstep(
+    lg: LayeredGraph,
+    delta_all: np.ndarray,
+    req_id: np.ndarray,
+    R: int,
+    origin: np.ndarray,
+    served: np.ndarray,
+    layers_used: np.ndarray,
+    reg,
+    obs: bool,
+) -> None:
+    """Greedy layered expansion of a mixed-origin batch, every request in
+    lockstep; fills ``served`` and ``layers_used`` in place."""
+    K = delta_all.shape[0]
+    D = delta_all.shape[1]
+    ar_K = np.arange(K)
+    ar_R = np.arange(R)
+    org_all = origin[req_id]
+    # Layer_0: local items first
+    local = delta_all[ar_K, org_all]
+    served[local] = org_all[local]
+
+    missing_per_req = np.bincount(req_id[served < 0], minlength=R)
+    if obs:
+        unresolved = int(missing_per_req.sum())
+        _obs_handles(reg).hits(0).inc(K - unresolved)
+    for layer in range(1, lg.n_layers + 1):
+        active = missing_per_req > 0
+        if not active.any():
+            break
+        if obs:
+            t_layer = time.perf_counter()
+        comp = lg.comp_of_dc[layer]  # [D]
+        allowed = comp[origin][:, None] == comp[None, :]  # [R, D]
+        allowed[ar_R, origin] = False
+        # route_online marks a layer "used" whenever its cluster is
+        # non-empty for a still-unresolved request, even if nothing is
+        # found there
+        has_cluster = allowed.any(axis=1)
+        layers_used[active & has_cluster] = layer
+        # greedy max-coverage, all active requests in lockstep: each pass
+        # computes every request's best cluster DC and assigns its hits —
+        # requests are independent, so lockstep == per-request greedy
+        while True:
+            miss = served < 0
+            if not miss.any():
+                break
+            # segment-sum coverage per request: D bincounts beat a slow
+            # ufunc.at scatter (D is a handful, the batch is the long axis)
+            cover = np.stack(
+                [
+                    np.bincount(req_id, weights=delta_all[:, d] * miss, minlength=R)
+                    for d in range(D)
+                ],
+                axis=1,
+            )
+            cover[~allowed] = 0.0
+            best = np.argmax(cover, axis=1)  # lowest-id tie-break
+            gain = cover[ar_R, best]
+            progress = gain > 0
+            if not progress.any():
+                break
+            hit = miss & progress[req_id] & delta_all[ar_K, best[req_id]]
+            served[hit] = best[req_id[hit]]
         missing_per_req = np.bincount(req_id[served < 0], minlength=R)
         if obs:
-            unresolved = int(missing_per_req.sum())
-            _obs_handles(reg).hits(0).inc(K - unresolved)
-        for layer in range(1, lg.n_layers + 1):
-            active = missing_per_req > 0
-            if not active.any():
-                break
-            if obs:
-                t_layer = time.perf_counter()
-            comp = lg.comp_of_dc[layer]  # [D]
-            allowed = comp[origin][:, None] == comp[None, :]  # [R, D]
-            allowed[ar_R, origin] = False
-            # route_online marks a layer "used" whenever its cluster is
-            # non-empty for a still-unresolved request, even if nothing is
-            # found there
-            has_cluster = allowed.any(axis=1)
-            layers_used[active & has_cluster] = layer
-            # greedy max-coverage, all active requests in lockstep: each pass
-            # computes every request's best cluster DC and assigns its hits —
-            # requests are independent, so lockstep == per-request greedy
-            while True:
-                miss = served < 0
-                if not miss.any():
-                    break
-                # segment-sum coverage per request: D bincounts beat a slow
-                # ufunc.at scatter (D is a handful, the batch is the long axis)
-                cover = np.stack(
-                    [
-                        np.bincount(req_id, weights=delta_all[:, d] * miss, minlength=R)
-                        for d in range(D)
-                    ],
-                    axis=1,
-                )
-                cover[~allowed] = 0.0
-                best = np.argmax(cover, axis=1)  # lowest-id tie-break
-                gain = cover[ar_R, best]
-                progress = gain > 0
-                if not progress.any():
-                    break
-                hit = miss & progress[req_id] & delta_all[ar_K, best[req_id]]
-                served[hit] = best[req_id[hit]]
-            missing_per_req = np.bincount(req_id[served < 0], minlength=R)
-            if obs:
-                # cumulative seconds as a counter (count comes from
-                # layer_hits' batch count): a scalar histogram observe costs
-                # ~10us in P² marker maths, which the 5% serving budget
-                # cannot spare
-                h = _obs_handles(reg)
-                h.layer_s(layer).inc(time.perf_counter() - t_layer)
-                now_unresolved = int(missing_per_req.sum())
-                h.hits(layer).inc(unresolved - now_unresolved)
-                unresolved = now_unresolved
+            # cumulative seconds as a counter (count comes from
+            # layer_hits' batch count): a scalar histogram observe costs
+            # ~10us in P² marker maths, which the 5% serving budget
+            # cannot spare
+            h = _obs_handles(reg)
+            h.layer_s(layer).inc(time.perf_counter() - t_layer)
+            now_unresolved = int(missing_per_req.sum())
+            h.hits(layer).inc(unresolved - now_unresolved)
+            unresolved = now_unresolved
 
-        if obs:
-            _obs_handles(reg).unresolved.inc(unresolved)
-
-    return _materialize_results(
-        env, sz_all, req_id, bounds, origin, served, layers_used,
-        R, D, reg, obs,
-    )
+    if obs:
+        _obs_handles(reg).unresolved.inc(unresolved)
 
 
 def _materialize_results(

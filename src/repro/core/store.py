@@ -256,7 +256,8 @@ class GeoGraphStore:
                 # serving.* counters/histograms are emitted batch-granular
                 # inside route_online_batch, where the flat arrays live
                 results = route_online_batch(
-                    self.lg, self.state, norm, registry=self._registry
+                    self.lg, self.state, norm, registry=self._registry,
+                    tracer=self.tracer,
                 )
             else:
                 results = [self._route_by_table(it, o) for it, o in norm]
@@ -267,7 +268,8 @@ class GeoGraphStore:
         if observe and norm:
             # heat injection grouped per origin inside the demand plane: one
             # scatter per DC touched, accumulated exactly once
-            self.demand.observe_requests(norm)
+            with self.tracer.span("demand.deposit", track="store", requests=len(norm)):
+                self.demand.observe_requests(norm)
         return results
 
     def _observe_serving(self, reg, norm, results: List[RouteResult]) -> None:

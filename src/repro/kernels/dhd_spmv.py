@@ -169,6 +169,7 @@ def dhd_ell_step_batch(
         out_specs=row_spec,
         out_shape=row_shape,
         interpret=interpret,
+        name="dhd_ell_count",
     )(h_u, h_nb, vals_t)
 
     delta = pl.pallas_call(
@@ -178,6 +179,7 @@ def dhd_ell_step_batch(
         out_specs=row_spec,
         out_shape=row_shape,
         interpret=interpret,
+        name="dhd_ell_flow",
     )(h_u, n_out, h_nb, n_out.reshape(-1)[flat], vals_t)
 
     return (1.0 - gamma) * (heat + delta[:, 0, :n]) + beta * q

@@ -8,7 +8,6 @@ validation.  ``use_kernel`` can be pinned explicitly by callers/tests.
 from __future__ import annotations
 
 import functools
-import time
 from collections import OrderedDict
 from typing import Optional, Tuple
 
@@ -39,17 +38,10 @@ __all__ = [
 
 
 # ------------------------------------------------------- dispatch telemetry
-def _obs_t0() -> Optional[float]:
-    """perf_counter() when telemetry is on, else None (zero-cost gate)."""
-    return time.perf_counter() if get_registry().enabled else None
-
-
-def _obs_dispatch(op: str, path: str, t0: Optional[float]) -> None:
-    if t0 is None:
-        return
+def _obs_dispatch(op: str, path: str) -> None:
     reg = get_registry()
-    reg.counter("kernels.dispatch", op=op, path=path).inc()
-    reg.histogram("kernels.op_time_s", op=op).observe(time.perf_counter() - t0)
+    if reg.enabled:
+        reg.counter("kernels.dispatch", op=op, path=path).inc()
 
 
 def on_tpu() -> bool:
@@ -211,7 +203,6 @@ def dhd_step(
     """
     if use_kernel is None:
         use_kernel = on_tpu()
-    t0 = _obs_t0()
     has_tail = tail_src is not None and tail_src.size > 0
     if has_tail:
         # Tail edges change |N_u^out| globally, so the blocked kernel cannot
@@ -224,17 +215,17 @@ def dhd_step(
         out = dhd_step_edges(
             heat, a, b, w, q, n, alpha=alpha, gamma=gamma, beta=beta
         )
-        _obs_dispatch("dhd_step", "tail_edges", t0)
+        _obs_dispatch("dhd_step", "tail_edges")
         return out
     if use_kernel:
         out = dhd_ell_step(
             heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta,
             block_n=block_n, interpret=not on_tpu(),
         )
-        _obs_dispatch("dhd_step", "kernel", t0)
+        _obs_dispatch("dhd_step", "kernel")
         return out
     out = ref.dhd_ell_ref(heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta)
-    _obs_dispatch("dhd_step", "ref", t0)
+    _obs_dispatch("dhd_step", "ref")
     return out
 
 
@@ -260,7 +251,6 @@ def dhd_step_batch(
     a per-adjacency operation)."""
     if use_kernel is None:
         use_kernel = on_tpu()
-    t0 = _obs_t0()
     has_tail = tail_src is not None and tail_src.size > 0
     if has_tail:
         if vals.ndim == 3:
@@ -272,19 +262,19 @@ def dhd_step_batch(
         out = dhd_step_edges_batch(
             heat, a, b, w, q, n, alpha=alpha, gamma=gamma, beta=beta
         )
-        _obs_dispatch("dhd_step_batch", "tail_edges", t0)
+        _obs_dispatch("dhd_step_batch", "tail_edges")
         return out
     if use_kernel:
         out = dhd_ell_step_batch(
             heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta,
             block_n=block_n, interpret=not on_tpu(),
         )
-        _obs_dispatch("dhd_step_batch", "kernel", t0)
+        _obs_dispatch("dhd_step_batch", "kernel")
         return out
     out = ref.dhd_ell_ref_batch(
         heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta
     )
-    _obs_dispatch("dhd_step_batch", "ref", t0)
+    _obs_dispatch("dhd_step_batch", "ref")
     return out
 
 
@@ -389,7 +379,6 @@ def diffuse_batch(
     else:
         h0 = seeds_j + jnp.asarray(np.atleast_2d(base_heat), jnp.float32)
     half_life = max(n_steps / 4.0, 1.0)
-    t0 = _obs_t0()
     if use_kernel:
         cols, vals = _ell_pack_batch(n_nodes, src, dst, weight)
         h = _diffuse_ell_loop(
@@ -398,7 +387,7 @@ def diffuse_batch(
             half_life=half_life, block_n=block_n,
             interpret=not on_tpu(),
         )
-        _obs_dispatch("diffuse_batch", "kernel", t0)
+        _obs_dispatch("diffuse_batch", "kernel")
     else:
         w = np.asarray(weight, np.float32)
         h = _diffuse_edges_loop(
@@ -407,7 +396,7 @@ def diffuse_batch(
             n_nodes=n_nodes, n_steps=n_steps,
             alpha=p.alpha, gamma=p.gamma, beta=p.beta, half_life=half_life,
         )
-        _obs_dispatch("diffuse_batch", "ref", t0)
+        _obs_dispatch("diffuse_batch", "ref")
     return np.asarray(h)
 
 
@@ -416,31 +405,25 @@ _route_expand_ref_jit = jax.jit(ref.route_expand_ref)
 
 
 # precomputed tag keys: the route dispatch sits inside the 5% serving
-# telemetry budget, so it books two plain counters (count + cumulative
-# seconds) instead of the P² histogram _obs_dispatch feeds
+# telemetry budget, so it books a memoized keyed counter handle
 _ROUTE_OBS_KEYS = {
-    path: ((("op", "route_expand"), ("path", path)),)
+    path: (("op", "route_expand"), ("path", path))
     for path in ("kernel", "ref", "subsets")
 }
 
 
-def _route_obs(path: str, t0: Optional[float]) -> None:
-    if t0 is None:
-        return
+def _route_obs(path: str) -> None:
     reg = get_registry()
-    # handle pair memoized per registry (dropped with the instruments by
-    # MetricsRegistry.clear()): two dict gets instead of two keyed lookups
+    if not reg.enabled:
+        return
+    # handle memoized per registry (dropped with the instruments by
+    # MetricsRegistry.clear()): one dict get instead of a keyed lookup
     cache_key = "kernels.route:" + path
-    pair = reg._handle_cache.get(cache_key)
-    if pair is None:
-        (key,) = _ROUTE_OBS_KEYS[path]
-        pair = (
-            reg.counter_keyed("kernels.dispatch", key),
-            reg.counter_keyed("kernels.route_expand_time_s", key),
-        )
-        reg._handle_cache[cache_key] = pair
-    pair[0].inc()
-    pair[1].inc(time.perf_counter() - t0)
+    counter = reg._handle_cache.get(cache_key)
+    if counter is None:
+        counter = reg.counter_keyed("kernels.dispatch", _ROUTE_OBS_KEYS[path])
+        reg._handle_cache[cache_key] = counter
+    counter.inc()
 
 
 def route_expand_candidates(
@@ -485,7 +468,6 @@ def route_expand_batch(
     R, K = bits.shape
     L = comp.shape[0] - 1
     D = comp.shape[1]
-    t0 = _obs_t0()
     if use_kernel is None or block_r is None:
         cfg = get_autotuner().lookup("route_expand", (R, K, D, L)) or {}
         if use_kernel is None:
@@ -509,11 +491,11 @@ def route_expand_batch(
             *args, block_r=int(block_r), interpret=interpret
         )
         out = tuple(np.asarray(o) for o in out)
-        _route_obs("kernel", t0)
+        _route_obs("kernel")
     else:
         out = _route_expand_ref_jit(*args)
         out = tuple(np.asarray(o) for o in out)
-        _route_obs("ref", t0)
+        _route_obs("ref")
     return out
 
 
@@ -556,7 +538,6 @@ def route_expand_subsets(
     ``(served [K] i64, layers_used [R] i64, miss_after [R, hier + 1] i64)``;
     the byte/latency fold is left to the caller's exact host epilogue.
     """
-    t0 = _obs_t0()
     R = int(n_requests)
     L = comp.shape[0] - 1
     D = comp.shape[1]
@@ -596,7 +577,7 @@ def route_expand_subsets(
             miss_cnt = (cnt * missing).sum(axis=1)
         miss_after[:, layer] = miss_cnt
     served = serve[req_id, bits_flat]
-    _route_obs("subsets", t0)
+    _route_obs("subsets")
     return served, layers_used, miss_after
 
 

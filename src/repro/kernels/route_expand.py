@@ -294,6 +294,7 @@ def route_expand(
             jax.ShapeDtypeStruct((r_pad, STATS_LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="route_expand",
     )(bits_p, sizes_p, lens_p, origin_p, allowed_p, oh_p, rtt_p, ibw_p)
 
     served = served_p[:R, :K]

@@ -15,10 +15,19 @@ Two ways to produce spans:
 
 Records are held in a bounded deque so a forgotten tracer can never grow
 without limit.
+
+Live spans of an enabled tracer are also mirrored into a running JAX
+profiler trace: each opens a ``jax.profiler.TraceAnnotation`` of the same
+name (tags that are ints, floats or strings become its metadata) and
+closes it on ``end()``, so one ``.xplane.pb`` carries the host phases on
+the device ops' clock.  jax is looked up in ``sys.modules`` and never
+imported here; without it the mirror does nothing.  ``record()`` spans
+carry simulated or computed timestamps, so they are not mirrored.
 """
 
 from __future__ import annotations
 
+import sys
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -46,11 +55,27 @@ class SpanRecord:
         return self.t1 - self.t0
 
 
+def _profiler_tags(tags: Dict[str, object]) -> Dict[str, object]:
+    return {k: v for k, v in tags.items() if isinstance(v, (int, float, str))}
+
+
+def _open_annotation(name: str, tags: Dict[str, object]):
+    """The span's ``jax.profiler.TraceAnnotation``, entered; None where jax
+    has not been imported."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name, **_profiler_tags(tags))
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """A live span; ``end()`` is idempotent and happens automatically when
     used as a context manager."""
 
-    __slots__ = ("_tracer", "sid", "name", "t0", "t1", "track", "parent", "tags")
+    __slots__ = ("_tracer", "sid", "name", "t0", "t1", "track", "parent", "tags",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", sid: int, name: str, t0: float,
                  track: str, parent: Optional[int], tags: Dict[str, object]):
@@ -62,6 +87,14 @@ class Span:
         self.track = track
         self.parent = parent
         self.tags = tags
+        self._ann = _open_annotation(name, tags)
+
+    def set_tags(self, **tags) -> None:
+        """Add tags known only once the span is open (its record and its
+        profiler annotation both carry them)."""
+        self.tags.update(tags)
+        if self._ann is not None:
+            self._ann.set_metadata(**_profiler_tags(tags))
 
     def elapsed_s(self) -> float:
         """Seconds since the span started (final duration once ended)."""
@@ -72,6 +105,8 @@ class Span:
     def end(self) -> float:
         if self.t1 is None:
             self.t1 = self._tracer.clock()
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
             self._tracer._finish(self)
         return self.t1 - self.t0
 
@@ -105,6 +140,9 @@ class _NoopSpan:
         if self.t1 is None:
             self.t1 = self._clock()
         return self.t1 - self.t0
+
+    def set_tags(self, **tags) -> None:
+        pass
 
     def __enter__(self) -> "_NoopSpan":
         return self
@@ -150,7 +188,8 @@ class Tracer:
 
     # -- span production ---------------------------------------------------
     def span(self, name: str, track: str = "main", **tags):
-        """Open a live span; use as a context manager or call ``end()``."""
+        """Open a live span; use as a context manager or call ``end()``.
+        A running JAX profiler records it as an annotation of the same name."""
         if not self.enabled:
             return _NoopSpan(self.clock)
         sid = self._next_sid
@@ -169,7 +208,8 @@ class Tracer:
         **tags,
     ) -> Optional[int]:
         """Record a span with explicit timestamps; returns its sid (or
-        ``None`` when disabled) so callers can parent children onto it."""
+        ``None`` when disabled) so callers can parent children onto it.
+        Not mirrored into the profiler: its times need not be wall time."""
         if not self.enabled:
             return None
         sid = self._next_sid

@@ -394,53 +394,76 @@ class AdmissionController:
         Guaranteed progress: either a batch is served, or the clock jumps to
         the next scheduled arrival (idle gaps are first offered to the
         attached maintenance policy).  Returns ``[]`` with nothing pending
-        and nothing scheduled."""
-        self._admit_due()
-        if self._demand is not None:
-            self._demand.advance_to(self.clock.now())
-        shard_key: Optional[int] = None
-        if self.cfg.per_shard_aimd and self._n_pending:
-            shard_key = self._next_shard_key()
-        if shard_key is not None:
-            target = self._targets.get(shard_key, self.batch_target)
-        else:
-            target = self._target_size()
-        waiting_to_fill = (
-            self.cfg.policy == "fixed"
-            and self._n_pending < target
-            and self._arrivals
-        )
-        if self._n_pending == 0 or waiting_to_fill:
-            if not self._arrivals:
-                if self._n_pending == 0:
-                    return []
-            else:
-                t_next = self._arrivals[0][0]
-                gap = t_next - self.clock.now()
-                if self.policy is not None and gap > 0 and self._n_pending == 0:
-                    # maintenance runs inside the gap; any overrun is
-                    # absorbed (the jump below caps the clock at t_next, so
-                    # serving is never pushed back).  Compaction is allowed
-                    # only when the remap hook keeps the scheduled handles'
-                    # item rows valid across the renumbering.
-                    self.policy.on_idle(
-                        self.clock.now(), gap, quiescent=self._remap_registered
-                    )
-                if gap > 0:
-                    self.idle_s += gap
-                self.clock.jump_to(t_next)
+        and nothing scheduled.
+
+        Traced as a live ``serve.step`` span (tag ``requests``: the batch
+        served) with children ``serve.form_batch`` (admission, the demand
+        window, batch formation) and ``serve.complete`` (everything after
+        ``serve_batch`` returns)."""
+        tracer = self.tracer
+        with tracer.span("serve.step", track="scheduler") as step_span:
+            with tracer.span("serve.form_batch", track="scheduler"):
                 self._admit_due()
+                if self._demand is not None:
+                    self._demand.advance_to(self.clock.now())
+                shard_key: Optional[int] = None
+                if self.cfg.per_shard_aimd and self._n_pending:
+                    shard_key = self._next_shard_key()
+                if shard_key is not None:
+                    target = self._targets.get(shard_key, self.batch_target)
+                else:
+                    target = self._target_size()
+                waiting_to_fill = (
+                    self.cfg.policy == "fixed"
+                    and self._n_pending < target
+                    and self._arrivals
+                )
+                idle = self._n_pending == 0 or waiting_to_fill
+                if not idle:
+                    batch = self._form_batch(target, shard_key=shard_key)
+            if idle:
+                self._idle()
                 return []
-        batch = self._form_batch(target, shard_key=shard_key)
-        t0 = self.clock.now()
-        t_wall = self._wall_clock()
-        try:
-            results = self.store.serve_batch([(h.items, h.origin) for h in batch])
-        except BaseException:
-            # nothing served, nothing lost: the whole batch returns to the
-            # queue fronts and the next step retries it
-            self._requeue(batch)
-            raise
+            step_span.set_tags(requests=len(batch))
+            t0 = self.clock.now()
+            t_wall = self._wall_clock()
+            try:
+                results = self.store.serve_batch([(h.items, h.origin) for h in batch])
+            except BaseException:
+                # nothing served, nothing lost: the whole batch returns to the
+                # queue fronts and the next step retries it
+                self._requeue(batch)
+                raise
+            with tracer.span("serve.complete", track="scheduler"):
+                self._complete(batch, results, target, t0, t_wall)
+            return batch
+
+    def _idle(self) -> None:
+        """Nothing to serve: jump the clock to the next scheduled arrival,
+        first offering the gap to the maintenance policy."""
+        if not self._arrivals:
+            return
+        t_next = self._arrivals[0][0]
+        gap = t_next - self.clock.now()
+        if self.policy is not None and gap > 0 and self._n_pending == 0:
+            # maintenance runs inside the gap; any overrun is
+            # absorbed (the jump below caps the clock at t_next, so
+            # serving is never pushed back).  Compaction is allowed
+            # only when the remap hook keeps the scheduled handles'
+            # item rows valid across the renumbering.
+            self.policy.on_idle(
+                self.clock.now(), gap, quiescent=self._remap_registered
+            )
+        if gap > 0:
+            self.idle_s += gap
+        self.clock.jump_to(t_next)
+        self._admit_due()
+
+    def _complete(self, batch: List[RequestHandle], results, target: int,
+                  t0: float, t_wall: float) -> None:
+        """Book a served batch: service time, per-request results and
+        latencies, deadline misses, span records, history and the AIMD
+        target."""
         if self.cfg.service_model == "measured":
             measured = getattr(self.store, "last_serve_seconds", None)
             compute_s = (
@@ -506,7 +529,6 @@ class AdmissionController:
         self._batch_size_sum += len(batch)
         self.clock.advance(compute_s)  # fetches overlap the next drain
         self._update_target(batch)
-        return batch
 
     def _miss_cause(self, h: RequestHandle, t0: float, compute_s: float) -> str:
         """Attribute a deadline miss to the first stage that overran.

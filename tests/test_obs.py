@@ -14,10 +14,18 @@ Bars under test:
   * scheduler miss-by-cause counts partition ``deadline_misses`` exactly
     and per-origin p99s cover every served origin (the BENCH_scheduler
     report fields);
-  * store reports (``apply_time_s``) are sourced from the span tree.
+  * store reports (``apply_time_s``) are sourced from the span tree;
+  * an enabled tracer's live spans land in a running JAX profiler trace as
+    host events with their tags, while disabled tracers and ``record()``
+    spans leave none, and ``import repro.obs`` loads no jax.
 """
+import glob
 import json
 import math
+import os
+import pathlib
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -290,6 +298,71 @@ def test_tracer_reset():
     assert tr.record("b", 0.0, 1.0) == 0  # sids restart
 
 
+# ------------------------------------------------- mirror into the profiler
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """Host events of one profiler session in which an enabled tracer, a
+    disabled one and ``record()`` each produced spans: name -> [stats]."""
+    import jax
+    from jax.profiler import ProfileData
+
+    d = tmp_path_factory.mktemp("profile")
+    on = Tracer(enabled=True)
+    off = Tracer(enabled=False)
+    jax.profiler.start_trace(str(d))
+    try:
+        with on.span("obs.outer", track="t", requests=3, impl="kernel", frac=0.5,
+                     skipped=[1]) as outer:
+            outer.set_tags(items=12)
+            with on.span("obs.inner", track="t"):
+                pass
+        with off.span("obs.disabled", track="t", requests=1):
+            pass
+        on.record("obs.recorded", 0.0, 1.0, track="t", requests=2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(d / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs."):
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns, dict(e.stats)))
+    return on, events
+
+
+def test_live_span_lands_in_profiler_trace(profiled):
+    tracer, events = profiled
+    (outer,), (inner,) = events["obs.outer"], events["obs.inner"]
+    # int, float and string tags pass, the late tag too; a list does not
+    assert outer[2] == {"requests": 3, "impl": "kernel", "frac": 0.5, "items": 12}
+    assert inner[2] == {}
+    assert outer[0] <= inner[0] and inner[0] + inner[1] <= outer[0] + outer[1]
+    # the tracer's own records are unchanged by the mirror
+    recs = {r.name: r for r in tracer.records}
+    assert recs["obs.outer"].tags == {"requests": 3, "impl": "kernel", "frac": 0.5,
+                                      "skipped": [1], "items": 12}
+    assert recs["obs.inner"].parent == recs["obs.outer"].sid
+
+
+def test_disabled_and_recorded_spans_leave_no_event(profiled):
+    _, events = profiled
+    assert "obs.disabled" not in events
+    assert "obs.recorded" not in events
+
+
+def test_import_obs_loads_no_jax():
+    import repro
+
+    src = str(pathlib.Path(repro.__file__).resolve().parents[1])
+    code = "import sys, repro.obs; print('jax' in sys.modules)"
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                       env=dict(os.environ, PYTHONPATH=src), timeout=120)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == "False"
+
+
 # --------------------------------------------------------------- exporters
 def test_chrome_export_shape_and_lanes(tmp_path):
     tr = Tracer(enabled=True)
@@ -368,7 +441,15 @@ def test_sim_clock_trace_export_is_deterministic():
     b = export_chrome_trace(tr_b)
     assert a == b  # byte-identical: same seed, same simulated timeline
     names = {r.name for r in tr_a.records}
-    assert {"request", "queue", "route", "wan_fetch", "drain"} <= names
+    assert {"request", "queue", "route", "wan_fetch", "drain",
+            "serve.step", "serve.form_batch", "serve.complete"} <= names
+    steps = [r for r in tr_a.records if r.name == "serve.step"]
+    served = [r for r in steps if "requests" in r.tags]
+    assert served and sum(r.tags["requests"] for r in served) == 40
+    by_sid = {r.sid: r for r in tr_a.records}
+    for r in tr_a.records:
+        if r.name in ("serve.form_batch", "serve.complete"):
+            assert by_sid[r.parent].name == "serve.step"
 
 
 def test_miss_causes_partition_deadline_misses():
@@ -404,3 +485,16 @@ def test_store_report_times_sourced_from_spans():
     # it must sit within the recorded span, a sliver under its duration
     assert 0.0 < report.apply_time_s <= recs[0].dur_s
     assert report.apply_time_s == pytest.approx(recs[0].dur_s, rel=0.05)
+
+
+def test_store_serve_batch_spans_the_demand_deposit():
+    store = _tiny_store(seed=4)
+    store.tracer = Tracer(enabled=True)
+    pats = [p for p in store.workload.patterns if len(p.items)][:6]
+    reqs = [(p.items, i % store.env.n_dcs) for i, p in enumerate(pats)]
+    store.serve_batch(reqs)
+    roots = [r for r in sorted(store.tracer.records, key=lambda r: r.t0)
+             if r.parent is None]
+    assert [r.name for r in roots] == ["store.serve_batch", "demand.deposit"]
+    assert roots[1].tags == {"requests": len(reqs)}
+    assert roots[0].t1 <= roots[1].t0

@@ -308,6 +308,49 @@ def test_fast_path_odd_batch_sizes(small_store, force_fast, R):
         assert x.latency_s == y.latency_s
 
 
+@pytest.mark.parametrize("path,stage", [
+    ("numpy", ("routing.expand", "numpy")),
+    ("subsets", ("routing.expand", "subsets")),
+    ("tile", ("routing.device", "ref")),
+])
+def test_traced_serving_is_bit_identical_and_nests(small_store, force_fast, monkeypatch,
+                                                   path, stage):
+    """Spans time the served path without touching it: the same batch gives
+    the same results with the store's tracer on as off, and the stages nest
+    under ``store.serve_batch`` in order."""
+    from repro.obs import Tracer
+
+    store = small_store
+    reqs = _store_requests(store.workload.patterns, store.lg.env.n_dcs)
+    if path == "numpy":
+        set_route_fast_config(RouteFastConfig(enabled=False))
+    if path == "tile":
+        monkeypatch.setattr(ops, "SUBSET_MAX_DCS", 0)
+    plain = store.serve_batch(reqs, observe=False)
+    tracer = Tracer(enabled=True)
+    monkeypatch.setattr(store, "tracer", tracer)
+    traced = store.serve_batch(reqs, observe=False)
+    for x, y in zip(plain, traced):
+        np.testing.assert_array_equal(x.served_by, y.served_by)
+        assert x.latency_s == y.latency_s
+        assert x.per_dc_latency == y.per_dc_latency
+        assert x.wan_bytes == y.wan_bytes
+        assert x.layers_used == y.layers_used and x.n_missing == y.n_missing
+    recs = sorted(tracer.records, key=lambda r: r.t0)
+    by_sid = {r.sid: r for r in recs}
+    top = [r for r in recs if r.parent is None]
+    assert [r.name for r in top] == ["store.serve_batch"]
+    kids = [r for r in recs if r.parent == top[0].sid]
+    assert [r.name for r in kids] == ["routing.prepare", stage[0], "routing.epilogue"]
+    assert kids[0].tags == {"requests": len(reqs),
+                            "items": sum(len(it) for it, _ in reqs)}
+    assert kids[1].tags["impl"] == stage[1]
+    (size,) = [r for r in recs if r.name == "routing.item_size"]
+    assert by_sid[size.parent].name == "routing.prepare"
+    for a, b in zip(kids, kids[1:]):
+        assert a.t1 <= b.t0
+
+
 def test_fast_flag_false_never_dispatches(small_store, monkeypatch):
     """fast=False must not touch the kernels module at all."""
     import repro.core.routing as routing
