@@ -401,7 +401,41 @@ def diffuse_batch(
 
 
 # ------------------------------------------------------ fused route expansion
-_route_expand_ref_jit = jax.jit(ref.route_expand_ref)
+@functools.partial(jax.jit, static_argnames=("use_kernel", "block_r", "interpret"))
+def _route_expand_packed(
+    bits, sizes, lens, origin, comp, rtt, ibw, *, use_kernel, block_r, interpret
+):
+    """The expansion (Pallas kernel or jnp oracle) and a pack of its six
+    outputs into one ``[R, K + L + D + 4]`` int32 array, so a call leaves
+    one buffer to fetch.  Columns: served (K), layers_used (1), miss_after
+    (L + 1), then the f32 outputs bytes_rd (D), straggler_s (1) and
+    wan_bytes (1), bit-cast (exact).  ``_unpack_route`` undoes it."""
+    i32 = jnp.int32
+    args = (
+        bits.astype(i32), sizes.astype(jnp.float32), lens.astype(i32),
+        origin.astype(i32), comp.astype(i32), rtt.astype(jnp.float32),
+        ibw.astype(jnp.float32),
+    )
+    if use_kernel:
+        out = _route_expand_kernel(*args, block_r=block_r, interpret=interpret)
+    else:
+        out = ref.route_expand_ref(*args)
+    served, bytes_rd, layers_used, miss_after, straggler, wan = out
+    f32_cols = jnp.concatenate([bytes_rd, straggler[:, None], wan[:, None]], axis=1)
+    return jnp.concatenate([
+        served, layers_used[:, None], miss_after,
+        jax.lax.bitcast_convert_type(f32_cols, i32),
+    ], axis=1)
+
+
+def _unpack_route(packed: np.ndarray, K: int, L: int, D: int) -> Tuple[np.ndarray, ...]:
+    """Column views of a fetched ``_route_expand_packed`` output, in the
+    order and dtypes of ``ref.route_expand_ref``."""
+    f = packed[:, K + L + 2:].view(np.float32)
+    return (
+        packed[:, :K], f[:, :D], packed[:, K], packed[:, K + 1:K + L + 2],
+        f[:, D], f[:, D + 1],
+    )
 
 
 # precomputed tag keys: the route dispatch sits inside the 5% serving
@@ -464,6 +498,12 @@ def route_expand_batch(
     the Pallas kernel and CPU the jitted oracle — both produce the oracle's
     exact greedy picks (``ref.route_expand_ref``).  Returns numpy
     ``(served, bytes_rd, layers_used, miss_after, straggler_s, wan_bytes)``.
+
+    One launch, one fetch: the four per-batch host arrays go up as
+    arguments of the one jitted call, whose dispatch uploads them together
+    (``comp``, ``rtt`` and ``ibw`` may already live on the device), and the
+    program returns the six outputs packed into a single int32 buffer,
+    fetched once and handed back as column views.
     """
     R, K = bits.shape
     L = comp.shape[0] - 1
@@ -477,26 +517,12 @@ def route_expand_batch(
             block_r = int(cfg.get("block_r", 128))
     if interpret is None:
         interpret = not on_tpu()
-    args = (
-        jnp.asarray(bits, jnp.int32),
-        jnp.asarray(sizes, jnp.float32),
-        jnp.asarray(lens, jnp.int32),
-        jnp.asarray(origin, jnp.int32),
-        jnp.asarray(comp, jnp.int32),
-        jnp.asarray(rtt, jnp.float32),
-        jnp.asarray(ibw, jnp.float32),
-    )
-    if use_kernel:
-        out = _route_expand_kernel(
-            *args, block_r=int(block_r), interpret=interpret
-        )
-        out = tuple(np.asarray(o) for o in out)
-        _route_obs("kernel")
-    else:
-        out = _route_expand_ref_jit(*args)
-        out = tuple(np.asarray(o) for o in out)
-        _route_obs("ref")
-    return out
+    packed = np.asarray(_route_expand_packed(
+        bits, sizes, lens, origin, comp, rtt, ibw,
+        use_kernel=bool(use_kernel), block_r=int(block_r), interpret=bool(interpret),
+    ))
+    _route_obs("kernel" if use_kernel else "ref")
+    return _unpack_route(packed, K, L, D)
 
 
 # subset-histogram router: with D data centers an item's routing behaviour is

@@ -6,6 +6,7 @@ router's exact greedy picks — same coverage argmax, same lowest-DC-id
 tie-break, same layer escalation — and the integrated fast path is
 bit-identical to the numpy batch path (shared exact f64 epilogue).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -202,6 +203,96 @@ def test_subsets_vs_oracle_property(seed, R, D, L, p):
     for r, k in enumerate(lens):
         np.testing.assert_array_equal(served[lo : lo + k], served_w[r, :k])
         lo += k
+
+
+# ------------------------------------- one packed output per batch call
+def _padded_problem(rng, r_pad, k_pad, D, L):
+    """A batch as the fast path hands it over: real requests in the first
+    rows, zero-length padded rows after them, zero-size slots past each
+    request's length, and every fifth item held by no DC (picked -1)."""
+    R = max(1, r_pad - r_pad // 4)
+    bits, sizes, lens, origin, comp, rtt, ibw = _rand_problem(
+        rng, R, 1, max(1, k_pad - 3), D, L, p_rep=0.3
+    )
+    K = bits.shape[1]
+    bits[:, ::5] = 0
+    bits_p = np.zeros((r_pad, k_pad), np.int32)
+    sizes_p = np.zeros((r_pad, k_pad), np.float32)
+    bits_p[:R, :K] = bits
+    sizes_p[:R, :K] = sizes
+    lens_p = np.zeros(r_pad, np.int32)
+    lens_p[:R] = lens
+    origin_p = np.zeros(r_pad, np.int32)
+    origin_p[:R] = origin
+    return bits_p, sizes_p, lens_p, origin_p, comp, rtt, ibw
+
+
+def _raw_bits(out):
+    """Outputs as exact bit patterns (f32 viewed as int32)."""
+    return [
+        np.asarray(o).view(np.int32) if np.asarray(o).dtype == np.float32
+        else np.asarray(o)
+        for o in out
+    ]
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("r_pad,k_pad,D,L", [
+    (8, 8, 3, 2),
+    (64, 128, 5, 3),
+    (256, 128, 8, 3),
+    (8, 1024, 16, 4),
+    (256, 1024, 5, 3),
+    (64, 8, 16, 2),
+])
+def test_packed_batch_matches_unpacked(r_pad, k_pad, D, L, use_kernel):
+    """``route_expand_batch`` packs six outputs into one int32 buffer and
+    unpacks them on the host: every output equals its unpacked
+    counterpart (the oracle jitted directly, or the interpreted kernel) bit
+    for bit, in dtype and shape, -1 picks and 0.0 padding included; the
+    other impl agrees on every pick and to f32 rounding on the sums."""
+    rng = np.random.default_rng(r_pad * 131 + k_pad * 7 + D)
+    prob = _padded_problem(rng, r_pad, k_pad, D, L)
+    lens = prob[2]
+    got = ops.route_expand_batch(*prob, use_kernel=use_kernel, block_r=128)
+    oracle = jax.jit(ref.route_expand_ref)(*prob)
+    kernel = route_expand(*prob, block_r=128, interpret=True)
+    same, other = (kernel, oracle) if use_kernel else (oracle, kernel)
+    for g, w in zip(got, same):
+        assert (g.dtype, g.shape) == (w.dtype, w.shape)
+    for g, w in zip(_raw_bits(got), _raw_bits(same)):
+        np.testing.assert_array_equal(g, w)
+    _assert_outputs_match(got, [np.asarray(o) for o in other], lens)
+    served, bytes_rd, _, _, straggler, wan = got
+    valid = np.arange(k_pad)[None, :] < lens[:, None]
+    assert (served[valid] == -1).any() and (served[~valid] == -1).all()
+    assert (straggler > 0).any()
+    pad_rows = lens == 0
+    assert pad_rows.any()
+    assert not bytes_rd[pad_rows].any() and not straggler[pad_rows].any()
+    assert not wan[pad_rows].any()
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_route_expand_batch_launches_one_program_with_one_output(
+    monkeypatch, use_kernel
+):
+    """One launch and one fetch per call: ``route_expand_batch`` runs one
+    jitted program, and that program lowers with a single output leaf."""
+    calls = []
+    packed = ops._route_expand_packed
+
+    def spy(*args, **kwargs):
+        calls.append((args, kwargs))
+        return packed(*args, **kwargs)
+
+    monkeypatch.setattr(ops, "_route_expand_packed", spy)
+    prob = _padded_problem(np.random.default_rng(5), 8, 128, 5, 3)
+    ops.route_expand_batch(*prob, use_kernel=use_kernel)
+    assert len(calls) == 1
+    args, kwargs = calls[0]
+    lowered = packed.lower(*args, **kwargs)
+    assert len(jax.tree_util.tree_leaves(lowered.out_info)) == 1
 
 
 # --------------------------------------------------- integrated fast path

@@ -78,6 +78,21 @@ def test_route_expand_compiles_for_v5e(one_chip, R, K, D, L, block_r):
     assert _kernels(_compile(fn, one_chip, *_route_shapes(R, K, D, L))) == ["route_expand"]
 
 
+def test_route_expand_packed_compiles_for_v5e(one_chip):
+    """The program ``ops.route_expand_batch`` launches on the chip, at the
+    widest batch the serving cell forms: the kernel keeps its name, and the
+    XLA module's name still holds ``route_expand`` (what the trace readers
+    match modules on)."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in _route_shapes(256, 1024, 5, 3)]
+    txt = ops._route_expand_packed.lower(
+        *args, use_kernel=True, block_r=128, interpret=False
+    ).compile().as_text()
+    assert _kernels(txt) == ["route_expand"]
+    module = re.match(r"HloModule (\S+?),", txt).group(1)
+    assert "route_expand" in module
+
+
 def test_dhd_ell_step_compiles_for_v5e(one_chip):
     n, kmax = 65536, 32
     f32 = jnp.float32
