@@ -1,18 +1,22 @@
 """Yardstick generators: graphs, pattern pools, requests, arrivals.
 
-Everything the benchmark feeds the store is made here from ``--seed``, so a
-later change to the program's own generators cannot change the work a cell
-measures.  ``community_graph`` and ``generate_khop_patterns`` are copies of
-the generators in ``repro.data.synthetic`` and ``repro.core.patterns`` as
-they stand when this benchmark was written; ``bench/tests/test_bench_gen.py``
-holds each copy to its original bit for bit at a small seed.  Graphs and patterns are returned as plain
-arrays and tuples; ``bench.harness`` wraps them in the program's types.
+Everything the benchmark feeds the store is made from ``--seed``, here or in
+a graph generator file ``bench/graphs/<generator>.py``, so a later change to
+the program's own generators cannot change the work a cell measures.
+``generate_khop_patterns`` (and ``bench/graphs/community_graph.py``) are
+copies of the generators in ``repro.core.patterns`` (and
+``repro.data.synthetic``) as they stood when this benchmark was written;
+``bench/tests/test_bench_gen.py`` holds each copy to its original bit for
+bit at a small seed.  Graphs and patterns are returned as plain arrays and
+tuples; ``bench.harness`` wraps them in the program's types.
 """
 from __future__ import annotations
 
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
+
+from bench import files
 
 
 class GraphArrays(NamedTuple):
@@ -24,6 +28,17 @@ class GraphArrays(NamedTuple):
     node_size: np.ndarray  # [n] float32 bytes
     edge_size: np.ndarray  # [m] float32 bytes
     partition: np.ndarray  # [n] int32 home DC
+    # tombstones of a graph that events have changed; None: every item alive
+    node_alive: Optional[np.ndarray] = None  # [n] bool
+    edge_alive: Optional[np.ndarray] = None  # [m] bool
+
+    def alive_items(self) -> np.ndarray:
+        """``[n + m]`` bool: which item ids are alive."""
+        m = len(self.src)
+        return np.concatenate([
+            np.ones(self.n_nodes, bool) if self.node_alive is None else self.node_alive,
+            np.ones(m, bool) if self.edge_alive is None else self.edge_alive,
+        ])
 
 
 class PatternArrays(NamedTuple):
@@ -34,7 +49,8 @@ class PatternArrays(NamedTuple):
     eta: float
 
 
-def _graph(n, src, dst, node_size, edge_size, partition) -> GraphArrays:
+def graph_arrays(n, src, dst, node_size, edge_size, partition) -> GraphArrays:
+    """A generated graph in the benchmark's dtypes, every item alive."""
     return GraphArrays(
         int(n), np.asarray(src, np.int32), np.asarray(dst, np.int32),
         np.asarray(node_size, np.float32), np.asarray(edge_size, np.float32),
@@ -42,65 +58,10 @@ def _graph(n, src, dst, node_size, edge_size, partition) -> GraphArrays:
     )
 
 
-# ------------------------------------------------------------------ graphs
-def _geo_partition(n: int, n_dcs: int, rng: np.random.Generator) -> np.ndarray:
-    cuts = np.sort(rng.choice(np.arange(1, n), size=n_dcs - 1, replace=False))
-    bounds = np.concatenate([[0], cuts, [n]])
-    part = np.zeros(n, dtype=np.int32)
-    for d in range(n_dcs):
-        part[bounds[d] : bounds[d + 1]] = d
-    return part
-
-
-def community_graph(n_nodes: int, n_communities: int = 8, p_in: float = 0.05,
-                    p_out: float = 0.002, seed: int = 0, n_dcs: int = 5,
-                    geo_affinity: float = 0.8) -> GraphArrays:
-    """Planted-partition graph; each community leans towards one home DC."""
-    rng = np.random.default_rng(seed)
-    comm = rng.integers(0, n_communities, size=n_nodes)
-    order = np.argsort(comm)
-    comm = comm[order]
-    src_l, dst_l = [], []
-    for ci in range(n_communities):
-        members = np.where(comm == ci)[0]
-        k = len(members)
-        if k < 2:
-            continue
-        m_in = rng.binomial(k * (k - 1) // 2, p_in)
-        s = members[rng.integers(0, k, size=m_in)]
-        d = members[rng.integers(0, k, size=m_in)]
-        src_l.append(s)
-        dst_l.append(d)
-    m_out = rng.binomial(n_nodes * (n_nodes - 1) // 2, p_out)
-    src_l.append(rng.integers(0, n_nodes, size=m_out))
-    dst_l.append(rng.integers(0, n_nodes, size=m_out))
-    src = np.concatenate(src_l)
-    dst = np.concatenate(dst_l)
-    mask = src != dst
-    src, dst = src[mask], dst[mask]
-    key = src.astype(np.int64) * n_nodes + dst
-    _, idx = np.unique(key, return_index=True)
-    src, dst = src[idx], dst[idx]
-    home_dc = rng.integers(0, n_dcs, size=n_communities)
-    partition = np.where(
-        rng.random(n_nodes) < geo_affinity,
-        home_dc[comm],
-        rng.integers(0, n_dcs, size=n_nodes),
-    )
-    sizes = rng.lognormal(mean=np.log(256.0), sigma=0.5, size=n_nodes).astype(np.float32)
-    esizes = rng.lognormal(mean=np.log(64.0), sigma=0.4, size=len(src)).astype(np.float32)
-    return _graph(n_nodes, src, dst, sizes, esizes, partition)
-
-
 def make_graph(spec: Dict, seed: int, n_dcs: int) -> GraphArrays:
-    """The graph a configuration's ``graph`` entry describes."""
-    kind = spec["generator"]
-    if kind == "community_graph":
-        return community_graph(
-            spec["n_nodes"], n_communities=spec["n_communities"], p_in=spec["p_in"],
-            p_out=spec["p_out"], seed=seed, n_dcs=n_dcs, geo_affinity=spec["geo_affinity"],
-        )
-    raise ValueError(f"unknown graph generator {kind!r}")
+    """The graph a configuration's ``graph`` entry describes, made by the
+    generator file ``bench/graphs/<generator>.py``."""
+    return files.load("graphs", spec["generator"]).make(spec, seed, n_dcs)
 
 
 # ---------------------------------------------------------------- patterns

@@ -9,6 +9,31 @@ A read's latency runs from its scheduled arrival to the return of the
 controller step that served it: queue wait, batching, host packing,
 transfers, kernel and epilogue.  The Eq. 1 WAN fetch the controller models
 is not a wall-clock cost of the store and is not in it.
+
+A traffic file may name an event source, ``"events": {"source": <name>,
+...}``: the file ``bench/events/<name>.py`` defines ``tiny(events)`` (its
+CPU rehearsal size) and the class ``Source``, built in :class:`Setup` as
+``Source(cfg, events, setup)`` (it generates all its events there, from the
+seed, in set-up), which acts on the store inside the window and provides:
+
+* ``warm(store)``: before the window, after the read shapes are warm;
+* ``step(store, now)``: once per iteration of the window's loop, before the
+  controller's step, inside the ``bench.event`` span; it decides itself
+  whether an event is due (open or closed loop).  No step runs after the
+  window's close;
+* ``version``: the number of events applied so far;
+* ``topology(version)``: the graph (:class:`bench.gen.GraphArrays`, with
+  its tombstones) after that many events;
+* ``keep(store)``: what its check needs of the store, taken after the
+  window and before the store is freed;
+* ``check(kept, setup)``: ``{number: {"value", "limit"}}``, numbers that
+  join the read check and decide ``correct`` (each at most its limit, a
+  constant in the source's file);
+* ``record()``: what its per-layer readers read, as ``ctx["events"]``.
+
+Each sampled read is checked against what served it: the replica rows of
+its items as the store held them when it was answered, and the topology of
+the events applied by then.
 """
 from __future__ import annotations
 
@@ -21,7 +46,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import gen, reference
+from . import files, gen, reference
 
 BENCH = pathlib.Path(__file__).resolve().parent
 REPO = BENCH.parent
@@ -125,10 +150,22 @@ class Setup:
             traffic["opening_backlog"],
         )
         self.store = None
-        self.replicas: Optional[np.ndarray] = None
+        self.replicas: Optional[np.ndarray] = None  # the replica map at the window's close
+        ev = traffic.get("events")
+        self.event_source = None if ev is None else ev["source"]
+        self.events = None if ev is None else files.load("events", ev["source"]).Source(
+            cfg, ev, self)
 
     def items(self, pattern: int) -> np.ndarray:
         return self.pool[pattern].items
+
+    def version(self) -> int:
+        """Events applied so far (0 without an event source)."""
+        return 0 if self.events is None else int(self.events.version)
+
+    def topology(self, version: int) -> gen.GraphArrays:
+        """The graph after ``version`` events."""
+        return self.graph if self.events is None else self.events.topology(version)
 
     def build_store(self) -> None:
         """The program's store over the generated graph and pool, placed with
@@ -182,12 +219,12 @@ def _warm_batches(st: Setup, rng: np.random.Generator) -> List[list]:
 
 def warm_up(st: Setup) -> None:
     """Compile every routing-expansion shape bucket the window uses before
-    it starts, then keep the replica map as placement left it: the reads
-    of the window are checked against routes over that map."""
+    it starts, then warm the event source, if any."""
     rng = np.random.default_rng(st.seed + 6)
     for batch in _warm_batches(st, rng):
         st.store.serve_batch(batch, observe=False)
-    st.replicas = np.array(st.store.state.delta, bool)
+    if st.events is not None:
+        st.events.warm(st.store)
 
 
 def settle_heap() -> None:
@@ -213,7 +250,10 @@ class Window:
         self.done = np.full(n, np.nan)  # completion, seconds after window start
         self.wait = np.full(n, np.nan)  # scheduled arrival -> dispatch
         self.serve_calls: List[tuple] = []  # (t0, t1, requests, items, serve_s)
+        # read -> (items, origin, result, replica rows of the items when it
+        # was answered, events applied by then)
         self.samples: Dict[int, tuple] = {}
+        self.events_kept = None  # the event source's keep(store)
         self.trace_dir: Optional[str] = None
         self.trace_span = (math.nan, math.nan)  # perf_counter instants
         self.trace_open = math.nan  # seconds after the window's start
@@ -295,7 +335,9 @@ def run_window(st: Setup, trace_dir: Optional[str] = None) -> Window:
             win.done[r] = t_ret
             win.wait[r] = h.t_dispatch - h.t_submit
             if r in sampled:
-                win.samples[r] = (np.array(h.items), h.origin, h.result)
+                items = np.array(h.items)
+                win.samples[r] = (items, h.origin, h.result, store.state.delta[items],
+                                  st.version())
 
     clock.start()
     while True:
@@ -315,6 +357,9 @@ def run_window(st: Setup, trace_dir: Optional[str] = None) -> Window:
                 win.trace_span = (win.trace_span[0], time.perf_counter())
                 jax.profiler.stop_trace()
                 tracing = False
+        if st.events is not None:
+            with jax.profiler.TraceAnnotation("bench.event", source=st.event_source):
+                st.events.step(store, now)
         while i < n and req.t[i] <= now:
             client.submit(st.items(int(req.pattern[i])), int(req.origin[i]),
                           at=float(req.t[i]))
@@ -380,39 +425,53 @@ def memory_peak_bytes() -> int:
 
 
 # ------------------------------------------------------------------- check
+def _sizes_and_components(g: gen.GraphArrays, reg: reference.Regions):
+    """Item bytes and the region components of the alive edges of ``g``."""
+    sizes = np.concatenate([g.node_size, g.edge_size]).astype(np.float64)
+    src, dst = g.src, g.dst
+    if g.edge_alive is not None:
+        src, dst = src[g.edge_alive], dst[g.edge_alive]
+    return sizes, reference.components(g.partition[src], g.partition[dst], reg)
+
+
 def check(st: Setup, win: Window, route_dtype=np.float64) -> Dict[str, Dict[str, float]]:
     """Each compared number beside its limit.  ``route_dtype`` puts the
     lower-precision control in the program's place (``bench/control.py``)."""
     lim = limits()
     nums: Dict[str, float] = {}
     n = len(st.requests.t)
-    g = st.graph
+    D = st.reg.n_dcs
     nums["reads_never_answered"] = float(np.isnan(win.done).sum())
     nums["sampled_reads"] = float(len(win.samples))
-    # the primary copies the configuration guarantees: each vertex at its
-    # partition DC, each edge at its source's
+    # the primary copies the configuration guarantees, in the map and the
+    # topology at the close: each alive vertex at its partition DC, each
+    # alive edge at its source's
+    g = st.topology(st.version())
     rep = st.replicas
     primary = np.concatenate([g.partition, g.partition[g.src]]).astype(np.int64)
-    if rep is None or rep.shape != (len(primary), st.reg.n_dcs):
-        nums["primary_copies_missing"] = float(len(primary))
-        rep = None
+    alive = np.where(g.alive_items())[0]
+    if rep is None or rep.shape != (len(primary), D):
+        nums["primary_copies_missing"] = float(len(alive))
     else:
-        nums["primary_copies_missing"] = float((~rep[np.arange(len(primary)), primary]).sum())
-    sizes = np.concatenate([g.node_size, g.edge_size]).astype(np.float64)
-    comp = reference.components(g.partition[g.src], g.partition[g.dst], st.reg)
+        nums["primary_copies_missing"] = float((~rep[alive, primary[alive]]).sum())
+    # each sampled read over the replica rows and the topology that served it
+    topo = {}
     mism = 0
     lat_gap = 0.0
     wan_gap = 0.0
     for r in sorted(win.samples):
-        items, origin, got = win.samples[r]
-        if rep is None:
+        items, origin, got, rows, version = win.samples[r]
+        if version not in topo:
+            topo[version] = _sizes_and_components(st.topology(version), st.reg)
+        sizes, comp = topo[version]
+        if len(items) and items.max() >= len(sizes):  # not an item of that topology
             mism += 1
             continue
         sz = sizes[items]
-        want = reference.route(rep[items], sz, origin, comp, st.reg)
+        want = reference.route(rows, sz, origin, comp, st.reg)
         served = got.served_by
         if route_dtype is not np.float64:
-            got = reference.route(rep[items], sz, origin, comp, st.reg, dtype=route_dtype)
+            got = reference.route(rows, sz, origin, comp, st.reg, dtype=route_dtype)
             served = got.served
         if not (np.array_equal(served, want.served) and int(got.layers_used) == want.layers_used):
             mism += 1
@@ -428,6 +487,11 @@ def check(st: Setup, win: Window, route_dtype=np.float64) -> Dict[str, Dict[str,
             out[k] = {"value": v, "limit": float(min(n, CHECK_SAMPLE))}
         else:
             out[k] = {"value": v, "limit": float(lim[k])}
+    if st.events is not None:
+        for k, c in st.events.check(win.events_kept, st).items():
+            if k in out:
+                raise ValueError(f"event source {st.event_source!r} reuses check name {k!r}")
+            out[k] = {"value": float(c["value"]), "limit": float(c["limit"])}
     return out
 
 
@@ -487,12 +551,19 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, seed: int, seconds: float,
         + ", ".join(f"{s}: {v:.1f}" for v, s in worst))
     log(f"# modelled Eq. 1 latency p99 (not in read latency): "
         f"{modelled_p99_ms(win):.3f} ms")
+    if st.events is not None:
+        log(f"# event source {st.event_source}: {st.version()} events applied in the window")
     ctx = None
     if trace:
         from . import tracefile
 
         ctx = {"st": st, "win": win, "registry": reg, "spans": spans, "e2e": e2e,
                "trace": tracefile.reduce(win.trace_dir) if win.trace_dir else None}
+        if st.events is not None:
+            ctx["events"] = st.events.record()
+    st.replicas = np.array(st.store.state.delta, bool)
+    if st.events is not None:
+        win.events_kept = st.events.keep(st.store)
     st.store = None  # the program's state is freed before the reference runs
     unsettle_heap()
     checks = check(st, win)

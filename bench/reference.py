@@ -1,11 +1,12 @@
 """Plain reference of what the store must compute, independent of the program.
 
 It imports nothing of ``repro``.  From the program it takes the results
-under test and the replica map as placement left it before the window
-(placement is an optimisation over diffused heat that the window does not
-drive; the map is the data the reads are answered from).  Every other input
-is the benchmark's own: the configuration's regions, the generated graph
-and the pattern pool.
+under test and, for each read, the replica rows of its items as the store
+held them when it answered (placement is an optimisation over diffused heat
+that the reference does not redo; the map is the data the reads are
+answered from).  Every other input is the benchmark's own: the
+configuration's regions, the generated graph (or the graph an event source
+made of it by then) and the pattern pool.
 
 * :func:`components` — Definitions 1-2 from scratch: each cross-region edge
   in the RTT bucket of its region pair, and the region components per layer.

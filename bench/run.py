@@ -3,9 +3,18 @@
     python3 bench/run.py --workload snb.read --seed 7 --seconds 30 --trace 0
 
 The cell (configuration, traffic mix, chips) is looked up by name in
-``BENCHMARK.json``; the configuration's file, the traffic file
-``bench/traffic/<mix>.json`` and the per-layer readers
-``bench/metrics/<metric>.py`` are found by their names.  The run refuses to
+``BENCHMARK.json``; everything else is found by name, so a deployment, a
+traffic mix or a metric joins the benchmark as new files and edits none:
+
+* the configuration's file (``configs[].file``), whose ``graph.generator``
+  names the graph generator ``bench/graphs/<generator>.py``;
+* the traffic file ``bench/traffic/<mix>.json``, whose optional
+  ``events.source`` names an event source ``bench/events/<source>.py``
+  that acts on the store inside the window and brings its own check and
+  limits (``bench/harness.py`` says what it provides);
+* the per-layer readers ``bench/metrics/<metric>.py``.
+
+``bench/files.py`` loads the three kinds of file.  The run refuses to
 measure anywhere but on a TPU with the chips the cell asks for.  It builds
 the store from the seed, warms every shape the window uses, measures for
 ``--seconds``, checks what the timed path produced against the plain
@@ -21,7 +30,6 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import pathlib  # noqa: E402
@@ -31,6 +39,8 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "src"))
+
+from bench import files  # noqa: E402
 
 
 def device_check(chips: int) -> dict:
@@ -46,11 +56,7 @@ def device_check(chips: int) -> dict:
 
 
 def load_reader(name: str):
-    path = REPO / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return files.load("metrics", name).read
 
 
 def result_line(cell: dict, spec: dict, res: dict, device: dict, trace: bool) -> dict:

@@ -25,6 +25,17 @@ def test_control_fails_the_read_cell(read_run):
     assert ctrl["route_mismatches"]["value"] == 0  # the picks are integers
 
 
+def test_reads_without_events_are_checked_over_the_map_placement_left(read_run):
+    """With no event source the map does not move in the window: every
+    sampled read's recorded rows are the map's at the close, at version 0."""
+    res, keep = read_run
+    st, win = keep["st"], keep["win"]
+    assert st.events is None and len(win.samples) == 384
+    for items, _, _, rows, version in win.samples.values():
+        assert version == 0
+        assert rows.dtype == bool and np.array_equal(rows, st.replicas[items])
+
+
 def test_a_missing_primary_copy_is_not_correct(read_run):
     res, keep = read_run
     st, win = keep["st"], keep["win"]

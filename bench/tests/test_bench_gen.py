@@ -1,15 +1,17 @@
 """Each generator copied into the benchmark reproduces the program's own bit
-for bit at a small seed, and the reference's region components are the
-program's layered graph's."""
+for bit at a small seed, the reference's region components are the
+program's layered graph's, and a graph generator is found by its file."""
 import json
 import pathlib
 
 import numpy as np
 import pytest
 
-from bench import gen, reference
+from bench import files, gen, reference
+from bench.tests.tiny import run_tiny
 
 CONFIG = pathlib.Path(__file__).resolve().parents[1] / "configs" / "ldbc-snb-sf10.5dc.json"
+community = files.load("graphs", "community_graph")
 
 
 def _same_graph(a, b):
@@ -24,14 +26,14 @@ def test_community_graph_matches_program(seed):
     from repro.data.synthetic import community_graph
 
     kw = dict(n_communities=7, p_in=0.05, p_out=0.002, seed=seed, n_dcs=5, geo_affinity=0.8)
-    _same_graph(gen.community_graph(500, **kw), community_graph(500, **kw))
+    _same_graph(community.community_graph(500, **kw), community_graph(500, **kw))
 
 
 def test_khop_patterns_match_program():
     from repro.core.graph import Graph, build_csr
     from repro.core.patterns import generate_khop_patterns
 
-    ga = gen.community_graph(400, n_communities=6, p_in=0.05, p_out=0.003, seed=8)
+    ga = community.community_graph(400, n_communities=6, p_in=0.05, p_out=0.003, seed=8)
     g = Graph(ga.n_nodes, ga.src, ga.dst, ga.node_size, ga.edge_size, ga.partition)
     csr = build_csr(g.n_nodes, g.src, g.dst, symmetrize=True)
     for hops, branch in ((1, 8), (2, 8), (3, 4)):
@@ -52,7 +54,7 @@ def test_reference_components_match_program(seed):
 
     cfg = json.loads(CONFIG.read_text())
     reg = reference.regions(cfg)
-    ga = gen.community_graph(400, n_communities=6, p_in=0.03, p_out=0.001, seed=seed)
+    ga = community.community_graph(400, n_communities=6, p_in=0.03, p_out=0.001, seed=seed)
     g = Graph(ga.n_nodes, ga.src, ga.dst, ga.node_size, ga.edge_size, ga.partition)
     lg = build_layered_graph(g, make_paper_env(),
                              latency_interval_s=cfg["regions"]["layer_interval_s"])
@@ -96,3 +98,52 @@ def test_seeds_draw_the_same_work_in_another_order():
 def test_exact_counts_keep_the_shares():
     got = np.bincount(gen.exact_counts(np.asarray([0.6, 0.3, 0.1]), 2048))
     assert got.sum() == 2048 and np.abs(got - np.asarray([0.6, 0.3, 0.1]) * 2048).max() < 1
+
+
+def test_configured_generator_is_its_file():
+    spec = json.loads(CONFIG.read_text())["graph"]
+    kw = {k: spec[k] for k in ("n_communities", "p_in", "p_out", "geo_affinity")}
+    small = dict(spec, n_nodes=300)
+    _same_graph(gen.make_graph(small, 2**31 + 3, 5),
+                community.community_graph(300, seed=2**31 + 3, n_dcs=5, **kw))
+
+
+def test_unknown_generator_names_the_file_looked_for():
+    with pytest.raises(FileNotFoundError, match=r"bench/graphs/no_such_graph\.py"):
+        gen.make_graph({"generator": "no_such_graph"}, 1, 5)
+    with pytest.raises(ValueError, match="not a graphs name"):
+        gen.make_graph({"generator": "../configs/x"}, 1, 5)
+
+
+UNIFORM = """
+import numpy as np
+
+from bench.gen import graph_arrays
+
+
+def make(spec, seed, n_dcs):
+    rng = np.random.default_rng(seed)
+    n = spec["n_nodes"]
+    key = np.unique(rng.integers(0, n * n, size=spec["n_edges"]))
+    src, dst = key // n, key % n
+    keep = src != dst
+    return graph_arrays(n, src[keep], dst[keep], np.full(n, 256.0), np.full(keep.sum(), 64.0),
+                        rng.integers(0, n_dcs, size=n))
+
+
+def tiny(spec):
+    return dict(spec, n_nodes=300, n_edges=2400)
+"""
+
+
+def test_generator_file_joins_without_edits(tmp_path, monkeypatch):
+    """A new generator is one new file: placed in a graphs folder of its
+    own, it builds, places and serves a tiny cell on the CPU."""
+    (tmp_path / "uniform_graph.py").write_text(UNIFORM)
+    monkeypatch.setitem(files.DIRS, "graphs", tmp_path)
+    keep = {}
+    _, res = run_tiny("snb.read", seed=2**31 + 29, keep=keep,
+                      graph={"generator": "uniform_graph", "n_nodes": 10**6, "n_edges": 10**7})
+    assert res["correct"], res["checks"]
+    g = keep["st"].graph
+    assert g.n_nodes == 300 and 2300 < len(g.src) <= 2400

@@ -67,3 +67,20 @@ def test_hand_made_profile():
         {"bench.serve_batch": 0.010, "bench.drain": 0.022, "bench.wait": 0.050})
     (span, dt), = tracefile.device_time_in(red, "bench.serve_batch", "route_expand")
     assert span["stats"] == {"requests": 3, "items": 30} and dt == pytest.approx(0.008)
+
+
+def test_idle_inside_an_event_is_charged_to_it():
+    dev = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Ops", events=[_ev("%a = f32[] add()", 10, 5)]),
+        NS(name="XLA Modules", events=[]),
+    ])
+    host = NS(name="/host:CPU", lines=[NS(name="python3", events=[
+        _ev("bench.event", 0, 40, source="toy"),
+        _ev("bench.drain", 40, 60),
+        _ev("bench.serve_batch", 50, 20, requests=1, items=3),
+    ])])
+    red = tracefile.reduce_profile(NS(planes=[dev, host]))
+    # gaps: 0-10 (event), 15-100 (mid 57.5: serve_batch)
+    assert red["idle_by_span"] == pytest.approx(
+        {"bench.event": 0.010, "bench.serve_batch": 0.085})
+    assert [s["stats"] for s in red["host"] if s["name"] == "bench.event"] == [{"source": "toy"}]

@@ -20,7 +20,7 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PREFIX = "bench."
 # innermost first: the span an idle gap is charged to
-SPAN_ORDER = ("bench.serve_batch", "bench.wait", "bench.drain")
+SPAN_ORDER = ("bench.serve_batch", "bench.wait", "bench.event", "bench.drain")
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:
