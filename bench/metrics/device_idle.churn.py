@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no operation ran on the chip."""
+
+
+def read(ctx):
+    red = ctx["trace"]
+    if not red or not red["devices"] or red["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - red["busy_s"] / red["window_s"])

@@ -535,6 +535,25 @@ class GeoGraphStore:
         (:func:`repro.core.graph.grow_item_rows`)."""
         return grow_item_rows(a, old_n, nv, ne, fill)
 
+    def prepare_updates(self) -> int:
+        """Compile ahead every device program ``apply_updates`` can run for
+        the batches within reach of the present size (the warm heat solve,
+        the frontier sub-solves and the row scatters, at each shape bucket
+        ``StreamingHeat.prepare`` names), so that a stream of batches
+        compiles nothing.  Changes no state: the replica map, heat field,
+        topology and route index are as they were.  Returns the number of
+        programs compiled."""
+        from ..streaming.delta_dhd import StreamingHeat
+
+        g = self.g
+        alive = (self._delta_graph.edge_alive if self._delta_graph is not None
+                 else np.ones(g.n_edges, bool))
+        heat = self._heat if self._heat is not None else StreamingHeat()
+        with self.tracer.span("store.prepare_updates", track="store") as sp:
+            made = heat.prepare(g.n_nodes, g.src[alive], g.dst[alive])
+            sp.set_tags(programs=made)
+        return made
+
     def apply_updates(self, batch) -> UpdateReport:
         """Absorb one :class:`~repro.streaming.MutationBatch` incrementally.
 
@@ -563,7 +582,8 @@ class GeoGraphStore:
         if self._delta_graph is None:
             self._delta_graph = DeltaGraph(self.g)
         dg = self._delta_graph
-        if batch.n_ops == 0:  # no-op batch: skip repair/heat entirely
+        if batch.n_ops == 0:  # no-op batch: ready the update path, change nothing
+            self.prepare_updates()
             return UpdateReport(0, 0, 0, 0, 0, None, None, root.elapsed_s())
         # mutations change the edge topology -> journaled region adjacency
         # and heat tables die (the id shift alone would be survivable now
@@ -575,6 +595,8 @@ class GeoGraphStore:
         nv, ne = res.n_new_vertices, len(res.new_edge_ids)
 
         # --- remap item-indexed state to the shifted id space -------------
+        remap = self.tracer.span("store.remap", track="store", new_vertices=int(nv),
+                                 new_edges=int(ne))
         self._item_uid = self._grow_item_rows(self._item_uid, old_n, nv, ne, -1)
         born = np.where(self._item_uid < 0)[0]
         self._item_uid[born] = np.arange(
@@ -606,6 +628,7 @@ class GeoGraphStore:
             cache.g = g2
             cache.edge_mask = dg.edge_alive
         self.g = g2
+        remap.end()
 
         # --- incremental layered-graph repair ----------------------------
         with self.tracer.span("store.repair_layers", track="store"):
@@ -646,7 +669,7 @@ class GeoGraphStore:
         # batch stream (any leftover residual is worked off by later batches).
         # The StreamingHeat defaults remain exact for standalone users.
         if self._heat is None:
-            self._heat = StreamingHeat(tol=1e-5, max_iters=32)
+            self._heat = StreamingHeat(tol=1e-5, max_iters=32, tracer=self.tracer)
         alive_e, w_e, q = self._heat_inputs()
         with self.tracer.span("store.warm_heat", track="store"):
             hstats = self._heat.update(

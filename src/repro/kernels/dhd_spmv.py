@@ -28,6 +28,11 @@ structure with a 2-D grid (batch × row-blocks); ``vals`` may be per-batch
 (``[B, n, kmax]``), which is how the placement arena diffuses every
 candidate's super-node topology in a single launch.  ``dhd_ell_step`` is
 the same kernels at B = 1.
+
+The coefficients ``alpha``, ``gamma`` and ``beta`` are float32 operands, not
+compile-time constants: the flow kernel reads ``alpha`` from SMEM, so a
+caller whose contraction clamp moves with the graph (the streaming warm
+solve) reuses one program per shape.
 """
 from __future__ import annotations
 
@@ -37,6 +42,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dhd_ell_step", "dhd_ell_step_batch"]
 
@@ -96,9 +102,8 @@ def _count_kernel(h_u_ref, h_nb_ref, vals_ref, nout_ref):
     nout_ref[0] = out_mask.astype(jnp.float32).sum(axis=0, keepdims=True)
 
 
-def _flow_kernel(
-    h_u_ref, nout_u_ref, h_nb_ref, nout_nb_ref, vals_ref, delta_ref, *, alpha: float
-):
+def _flow_kernel(alpha_ref, h_u_ref, nout_u_ref, h_nb_ref, nout_nb_ref, vals_ref, delta_ref):
+    alpha = alpha_ref[0]
     h_u = h_u_ref[0]  # [1, block_n]
     nout_u = jnp.maximum(nout_u_ref[0], 1.0)
     h_nb = h_nb_ref[0]  # [kmax, block_n]
@@ -115,17 +120,15 @@ def _flow_kernel(
     delta_ref[0] = inflow - outflow
 
 
-@functools.partial(
-    jax.jit, static_argnames=("alpha", "gamma", "beta", "block_n", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def dhd_ell_step_batch(
     heat: jnp.ndarray,  # [B, n] float32
     cols: jnp.ndarray,  # [n, kmax] int32 shared symmetric ELL (pad = self)
     vals: jnp.ndarray,  # [n, kmax] shared or [B, n, kmax] per-batch weights
     q: jnp.ndarray,  # [B, n] source heat
-    alpha: float = 0.5,
-    gamma: float = 0.1,
-    beta: float = 0.3,
+    alpha=0.5,  # float32 operands: a new value reuses the compiled program
+    gamma=0.1,
+    beta=0.3,
     block_n: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -161,6 +164,9 @@ def dhd_ell_step_batch(
     else:
         vals_spec = pl.BlockSpec((kmax, block_n), lambda bb, i: (0, i))
     row_shape = jax.ShapeDtypeStruct((b, 1, n_pad), jnp.float32)
+    alpha = jnp.reshape(jnp.asarray(alpha, jnp.float32), (1,))
+    gamma = jnp.asarray(gamma, jnp.float32)
+    beta = jnp.asarray(beta, jnp.float32)
 
     n_out = pl.pallas_call(
         _count_kernel,
@@ -173,29 +179,28 @@ def dhd_ell_step_batch(
     )(h_u, h_nb, vals_t)
 
     delta = pl.pallas_call(
-        functools.partial(_flow_kernel, alpha=alpha),
+        _flow_kernel,
         grid=grid,
-        in_specs=[row_spec, row_spec, tile_spec, tile_spec, vals_spec],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), row_spec, row_spec, tile_spec,
+                  tile_spec, vals_spec],
         out_specs=row_spec,
         out_shape=row_shape,
         interpret=interpret,
         name="dhd_ell_flow",
-    )(h_u, n_out, h_nb, n_out.reshape(-1)[flat], vals_t)
+    )(alpha, h_u, n_out, h_nb, n_out.reshape(-1)[flat], vals_t)
 
     return (1.0 - gamma) * (heat + delta[:, 0, :n]) + beta * q
 
 
-@functools.partial(
-    jax.jit, static_argnames=("alpha", "gamma", "beta", "block_n", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def dhd_ell_step(
     heat: jnp.ndarray,  # [n] float32
     cols: jnp.ndarray,  # [n, kmax] int32 symmetric ELL (pad = self)
     vals: jnp.ndarray,  # [n, kmax] float32 (0 where padded)
     q: jnp.ndarray,  # [n] source heat
-    alpha: float = 0.5,
-    gamma: float = 0.1,
-    beta: float = 0.3,
+    alpha=0.5,  # float32 operands, as in dhd_ell_step_batch
+    gamma=0.1,
+    beta=0.3,
     block_n: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:

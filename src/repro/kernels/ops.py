@@ -310,10 +310,7 @@ def _ell_pack_batch(
     return cols, vals
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_nodes", "n_steps", "alpha", "gamma", "beta", "half_life"),
-)
+@functools.partial(jax.jit, static_argnames=("n_nodes", "n_steps", "half_life"))
 def _diffuse_edges_loop(
     src, dst, weight, h0, q0, *, n_nodes, n_steps, alpha, gamma, beta, half_life
 ):
@@ -330,10 +327,7 @@ def _diffuse_edges_loop(
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_steps", "alpha", "gamma", "beta", "half_life", "block_n", "interpret"
-    ),
+    jax.jit, static_argnames=("n_steps", "half_life", "block_n", "interpret")
 )
 def _diffuse_ell_loop(
     cols, vals, h0, q0, *,
@@ -367,10 +361,12 @@ def diffuse_batch(
 
     Runs the whole decaying-source loop on device: the batched Pallas ELL
     kernel (edge list packed tail-free once per call) when kernel-eligible,
-    the vmapped edge form otherwise."""
+    the vmapped edge form otherwise.  The DHD coefficients are float32
+    operands of both loops, so other coefficients reuse the program."""
     from ..core.dhd import DHDParams
 
     p = params or DHDParams()
+    coef = {k: np.float32(getattr(p, k)) for k in ("alpha", "gamma", "beta")}
     if use_kernel is None:
         use_kernel = on_tpu()
     seeds_j = jnp.asarray(seeds, jnp.float32)
@@ -383,9 +379,8 @@ def diffuse_batch(
         cols, vals = _ell_pack_batch(n_nodes, src, dst, weight)
         h = _diffuse_ell_loop(
             jnp.asarray(cols), jnp.asarray(vals), h0, seeds_j,
-            n_steps=n_steps, alpha=p.alpha, gamma=p.gamma, beta=p.beta,
-            half_life=half_life, block_n=block_n,
-            interpret=not on_tpu(),
+            n_steps=n_steps, half_life=half_life, block_n=block_n,
+            interpret=not on_tpu(), **coef,
         )
         _obs_dispatch("diffuse_batch", "kernel")
     else:
@@ -393,8 +388,7 @@ def diffuse_batch(
         h = _diffuse_edges_loop(
             jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
             jnp.asarray(w), h0, seeds_j,
-            n_nodes=n_nodes, n_steps=n_steps,
-            alpha=p.alpha, gamma=p.gamma, beta=p.beta, half_life=half_life,
+            n_nodes=n_nodes, n_steps=n_steps, half_life=half_life, **coef,
         )
         _obs_dispatch("diffuse_batch", "ref")
     return np.asarray(h)

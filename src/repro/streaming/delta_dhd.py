@@ -18,13 +18,15 @@ rebuilt.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.dhd import DHDParams, steady_state
 from ..kernels import ops
+from ..obs import Tracer, get_registry
 
 __all__ = ["StreamingHeat", "WarmStats", "STREAMING_DHD_PARAMS"]
 
@@ -45,19 +47,37 @@ class WarmStats:
     residual: float  # sup-norm step size at exit: carried-over staleness
 
 
-def _round8(k: int) -> int:
-    return max(8, int(np.ceil(k / 8.0)) * 8)
+# Shapes are bucketed so that a stream of batches runs a handful of compiled
+# programs, each compiled once (``StreamingHeat.prepare`` compiles them ahead).
+# The field's rows go to 16 steps an octave (at most 1/16 of padding, since
+# every sweep gathers over every slot of every row), rounded to the kernel's
+# 256-row block; the ELL width to a multiple of 8 slots; the frontier
+# sub-solve's rows and the scattered row sets to a power of two.  Pad rows
+# are isolated self-loops with zero weight and zero source, so they hold heat
+# 0 forever; pad entries of a row scatter re-write the last real row with its
+# own contents.
+_ROW_MIN = 1024
+_SCATTER_MIN = 64
+# the ELL keeps this many slots free above the widest row at a cold build,
+# so streaming edge growth rarely overflows a row (an overflow rebuilds);
+# prepare() also reaches a row growing by this many edges
+_K_HEADROOM = 8
 
 
-# Rows are padded to a multiple of this: shapes stay stable across growth
-# batches (no per-batch recompiles) and satisfy the Pallas kernel's block
-# divisibility, keeping the TPU hot path eligible.  Pad rows are isolated
-# self-loops with zero weight and zero source, so they hold heat 0 forever.
-_ROW_PAD = 256
+def _pow2(n: int, least: int) -> int:
+    return max(least, 1 << max(0, int(n) - 1).bit_length())
 
 
-def _padded(n: int) -> int:
-    return max(_ROW_PAD, int(np.ceil(n / _ROW_PAD)) * _ROW_PAD)
+def _field_bucket(n: int) -> int:
+    """Rows of the field for ``n`` vertices: the least of ``m * 2^e`` (``m``
+    in 16..31) that holds them, rounded up to a multiple of 256."""
+    n = max(int(n), _ROW_MIN)
+    e = max(0, n.bit_length() - 5)  # n < 32 * 2^e
+    return -(-(-(-n // (1 << e)) << e) // 256) * 256
+
+
+def _k_bucket(k: int) -> int:
+    return max(8, -(-int(k) // 8) * 8)
 
 
 def _sym_halves(
@@ -103,11 +123,65 @@ def _fill_rows(
     return True
 
 
+def _heat_relax(heat, cols, vals, q, frozen, frozen_heat, coef, tol, max_iters, *, use_kernel):
+    """Sweeps ``heat <- step(heat)`` until one moves no entry by ``tol`` or
+    ``max_iters`` ran; rows in ``frozen`` stay at ``frozen_heat``.  Returns
+    the field, the sweeps run, and the sup-norm move of one more sweep."""
+
+    def sweep(h):
+        nh = ops.dhd_step(h, cols, vals, q, alpha=coef[0], gamma=coef[1], beta=coef[2],
+                          use_kernel=use_kernel)
+        return jnp.where(frozen, frozen_heat, nh)
+
+    h, it = steady_state(heat, lambda hh, _: sweep(hh), lambda k: q,
+                         max_iters=max_iters, tol=tol)
+    return h, it, jnp.max(jnp.abs(sweep(h) - h))
+
+
+def _heat_set_rows(cols, vals, idx, rows_cols, rows_vals):
+    return cols.at[idx].set(rows_cols), vals.at[idx].set(rows_vals)
+
+
+# compiled programs by (kind, shapes, kernel path); shared by every instance
+_PROGRAMS: Dict[tuple, object] = {}  # geolint: allow[GL001] — reset_programs()
+
+
+def reset_programs() -> None:
+    """Drop every compiled program (test isolation; each recompiles on use)."""
+    _PROGRAMS.clear()
+
+
+def _program(kind: str, rows: int, kmax: int, r: int = 0):
+    """The compiled relaxation over ``[rows, kmax]`` or row scatter of ``r``
+    rows into it, compiled on first use."""
+    use_kernel = ops.on_tpu()
+    key = (kind, rows, kmax, r, use_kernel)
+    prog = _PROGRAMS.get(key)
+    if prog is None:
+        f32, i32 = jnp.float32, jnp.int32
+        sd = jax.ShapeDtypeStruct
+        ell = (sd((rows, kmax), i32), sd((rows, kmax), f32))
+        if kind == "relax":
+            vec = sd((rows,), f32)
+            args = (vec, *ell, vec, sd((rows,), jnp.bool_), vec, sd((3,), f32),
+                    sd((), f32), sd((), i32))
+            low = jax.jit(_heat_relax, static_argnames="use_kernel").lower(
+                *args, use_kernel=use_kernel)
+        else:
+            args = (*ell, sd((r,), i32), sd((r, kmax), i32), sd((r, kmax), f32))
+            low = jax.jit(_heat_set_rows).lower(*args)
+        prog = _PROGRAMS[key] = low.compile()
+    return prog
+
+
 class StreamingHeat:
     """Persistent DHD equilibrium over the alive graph, warm-updated per batch.
 
     ``rebuild`` performs the cold construction (and is the overflow fallback);
-    ``update`` patches the touched ELL rows and re-solves warm.
+    ``update`` patches the touched ELL rows and re-solves warm; ``prepare``
+    compiles ahead every program the next batches can run.  Each solve is
+    one compiled program per shape bucket, with ``alpha``, the tolerance and
+    the sweep budget as operands.
     """
 
     def __init__(
@@ -115,16 +189,19 @@ class StreamingHeat:
         params: DHDParams = STREAMING_DHD_PARAMS,
         max_iters: int = 300,
         tol: float = 1e-6,
+        tracer: Optional[Tracer] = None,
     ) -> None:
         self.params = params
         self.alpha = params.alpha  # clamped per-graph by _effective_alpha
         self.max_iters = max_iters
         self.tol = tol
+        self.tracer = tracer if tracer is not None else Tracer()
         self.n_nodes = 0
-        self.cols: Optional[np.ndarray] = None  # [n, kmax] int32
-        self.vals: Optional[np.ndarray] = None  # [n, kmax] float32
-        self.heat: Optional[np.ndarray] = None  # [n] float32
-        self.q: Optional[np.ndarray] = None  # [n] float32
+        self.n_edges = 0  # alive undirected edges of the last build or update
+        self.cols: Optional[np.ndarray] = None  # [n_pad, kmax] int32
+        self.vals: Optional[np.ndarray] = None  # [n_pad, kmax] float32
+        self.heat: Optional[np.ndarray] = None  # [n_pad] float32
+        self.q: Optional[np.ndarray] = None  # [n_pad] float32
         # staleness metric: sup-norm change of one more sweep from the field
         # the last solve() exited with (0 at equilibrium, >0 when the sweep
         # budget ran out first).  Surfaced via WarmStats / UpdateReport.
@@ -136,13 +213,25 @@ class StreamingHeat:
     def _sync_device(self, rows: Optional[np.ndarray] = None) -> None:
         """Mirror cols/vals to device — full upload, or a row scatter when
         only ``rows`` changed (saves the [n, kmax] host->device copy that
-        otherwise dominates small warm updates)."""
-        if rows is None or self._cols_j is None or self._cols_j.shape != self.cols.shape:
-            self._cols_j = jnp.asarray(self.cols)
-            self._vals_j = jnp.asarray(self.vals)
-        elif len(rows):
-            self._cols_j = self._cols_j.at[rows].set(jnp.asarray(self.cols[rows]))
-            self._vals_j = self._vals_j.at[rows].set(jnp.asarray(self.vals[rows]))
+        otherwise dominates small warm updates).  The scatter's row count is
+        padded to a power of two, so a few programs serve every batch."""
+        n_pad = self.cols.shape[0]
+        full = rows is None or self._cols_j is None or self._cols_j.shape != self.cols.shape
+        r = 0 if full or not len(rows) else _pow2(len(rows), _SCATTER_MIN)
+        full = full or r > n_pad // 2
+        with self.tracer.span("heat.sync", track="store",
+                              rows=n_pad if full else len(rows),
+                              bucket=n_pad if full else r):
+            if full:
+                self._cols_j = jnp.asarray(self.cols)
+                self._vals_j = jnp.asarray(self.vals)
+            elif r:
+                idx = np.full(r, rows[-1], np.int32)
+                idx[: len(rows)] = rows
+                prog = _program("scatter", n_pad, self.cols.shape[1], r)
+                self._cols_j, self._vals_j = prog(
+                    self._cols_j, self._vals_j, idx, self.cols[idx], self.vals[idx])
+                get_registry().counter("heat.scatter_rows", bucket=r).inc(len(rows))
 
     @property
     def vertex_heat(self) -> Optional[np.ndarray]:
@@ -170,6 +259,54 @@ class StreamingHeat:
         safe = 0.5 * p.gamma / ((1.0 - p.gamma) * bound)
         return min(p.alpha, safe)
 
+    # ---------------------------------------------------------- programs
+    def prepare(self, n_nodes: int, src: np.ndarray, dst: np.ndarray) -> int:
+        """Compile every program the next batches can run, changing no state.
+
+        Reachable from the present graph (``n_nodes`` vertices, alive edges
+        ``src``/``dst``): the field's rows, and the next bucket once fewer
+        than 1/32 of them are free; the ELL width in use (or the one a cold
+        build picks) and each width a rebuild picks while the widest row
+        grows by up to ``_K_HEADROOM`` edges; every frontier sub-solve size
+        below the field; each row-scatter size up to half the field.
+        Returns the programs made."""
+        deg = np.bincount(np.concatenate([src, dst]).astype(np.int64), minlength=n_nodes)
+        d = int(deg.max(initial=1))
+        grown = range(d, d + _K_HEADROOM + 1)
+        if self.cols is not None:
+            n_pad, kmax = self.cols.shape
+            widths = {kmax} | {_k_bucket(x + _K_HEADROOM) for x in grown if x > kmax}
+        else:
+            n_pad = _field_bucket(n_nodes)
+            widths = {_k_bucket(x + _K_HEADROOM) for x in grown}
+        fields = [n_pad] + ([_field_bucket(n_pad + 1)] if 32 * (n_pad - n_nodes) < n_pad else [])
+        before = len(_PROGRAMS)
+        for k in sorted(widths):
+            for n in fields:
+                _program("relax", n, k)
+                r = _SCATTER_MIN
+                while r <= n // 2:
+                    _program("scatter", n, k, r)
+                    r *= 2
+            rows = _ROW_MIN
+            while rows < fields[-1]:
+                _program("relax", rows, k)
+                rows *= 2
+        return len(_PROGRAMS) - before
+
+    def _relax(self, heat, cols_j, vals_j, q, frozen=None, frozen_heat=None,
+               max_iters: Optional[int] = None, tol: Optional[float] = None):
+        """One compiled relaxation; returns (field, sweeps, residual) on device."""
+        rows, kmax = cols_j.shape
+        if frozen is None:
+            frozen = np.zeros(rows, bool)
+            frozen_heat = np.zeros(rows, np.float32)
+        p = self.params
+        coef = np.asarray([self.alpha, p.gamma, p.beta], np.float32)
+        return _program("relax", rows, kmax)(
+            heat, cols_j, vals_j, q, frozen, frozen_heat, coef,
+            np.float32(tol or self.tol), np.int32(max_iters or self.max_iters))
+
     # ----------------------------------------------------------- cold path
     def rebuild(
         self,
@@ -184,14 +321,14 @@ class StreamingHeat:
 
         ``heat0`` warm-seeds the solve from a prior field of length
         ``n_nodes`` — the compaction re-key path, where the topology arrays
-        are renumbered but the equilibrium is (row-permuted) unchanged."""
+        are renumbered but the equilibrium is (row-permuted) unchanged, and
+        the overflow fallback, which rebuilds wider rows under the same field."""
         uu, vv, ww = _sym_halves(src, dst, weights)
         deg = np.bincount(uu, minlength=n_nodes) if len(uu) else np.zeros(n_nodes, np.int64)
-        # one extra octet of headroom so streaming edge growth rarely
-        # overflows a row (overflow forces a cold rebuild + recompile)
-        kmax = _round8(int(deg.max(initial=1)) + 8)
-        n_pad = _padded(n_nodes)
+        kmax = _k_bucket(int(deg.max(initial=1)) + _K_HEADROOM)
+        n_pad = _field_bucket(n_nodes)
         self.n_nodes = n_nodes
+        self.n_edges = len(src)
         self.cols = np.repeat(np.arange(n_pad, dtype=np.int32)[:, None], kmax, axis=1)
         self.vals = np.zeros((n_pad, kmax), np.float32)
         if len(uu):
@@ -206,38 +343,28 @@ class StreamingHeat:
         return self.solve()
 
     # --------------------------------------------------------------- solve
-    def _sweep(
-        self, heat: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray, q: jnp.ndarray
-    ) -> jnp.ndarray:
-        p = self.params
-        return ops.dhd_step(
-            heat, cols, vals, q, alpha=self.alpha, gamma=p.gamma, beta=p.beta
-        )
-
-    def solve(self, max_iters: Optional[int] = None, tol: Optional[float] = None) -> int:
+    def solve(self, max_iters: Optional[int] = None, tol: Optional[float] = None,
+              local_iters: int = 0) -> int:
         """Full-graph sweeps from the current field until the residual < tol.
 
-        Runs through :func:`repro.core.dhd.steady_state` (``lax.while_loop``)
-        so the whole fixed-point iteration stays on device."""
-        max_iters = max_iters or self.max_iters
-        tol = tol or self.tol
+        One compiled program (``lax.while_loop``) runs the whole fixed-point
+        iteration on device and one more probe sweep that prices the
+        carried-over staleness: how far one more iteration would still move
+        the field (0 when converged within tol).  ``local_iters`` only tags
+        the span: the frontier sweeps that ran before it."""
         if self._cols_j is None:
             self._sync_device()
-        cols = self._cols_j
-        vals = self._vals_j
-        q = jnp.asarray(self.q)
-        h, it = steady_state(
-            jnp.asarray(self.heat),
-            lambda hh, qq: self._sweep(hh, cols, vals, qq),
-            lambda k: q,
-            max_iters=max_iters,
-            tol=tol,
-        )
-        self.heat = np.array(h)  # np.array: jax buffers are read-only views
-        # one probe sweep prices the carried-over staleness: how far one more
-        # iteration would still move the field (0 when converged within tol)
-        self.residual = float(jnp.max(jnp.abs(self._sweep(h, cols, vals, q) - h)))
-        return int(it)
+        n_pad, kmax = self.cols.shape
+        with self.tracer.span("heat.sweeps", track="store", n_pad=n_pad, k_pad=kmax,
+                              nodes=self.n_nodes, edges=self.n_edges,
+                              local=int(local_iters)) as sp:
+            h, it, res = self._relax(self.heat, self._cols_j, self._vals_j, self.q,
+                                     max_iters=max_iters, tol=tol)
+            self.heat = np.array(h)  # np.array: jax buffers are read-only views
+            self.residual = float(res)
+            it = int(it)
+            sp.set_tags(iters=it)
+        return it
 
     # ---------------------------------------------------------- warm path
     def _neighbors_of(self, mask: np.ndarray) -> np.ndarray:
@@ -271,7 +398,7 @@ class StreamingHeat:
             return WarmStats(n_nodes, 0, 0, it, self.residual)
         n_pad_old = self.cols.shape[0]
         if n_nodes > n_pad_old:
-            n_pad = _padded(n_nodes)
+            n_pad = _field_bucket(n_nodes)
             kmax = self.cols.shape[1]
             extra = n_pad - n_pad_old
             pad_cols = np.repeat(
@@ -281,14 +408,15 @@ class StreamingHeat:
             self.vals = np.concatenate([self.vals, np.zeros((extra, kmax), np.float32)])
             self.heat = np.concatenate([self.heat, np.zeros(extra, np.float32)])
         self.n_nodes = n_nodes
+        self.n_edges = len(src)
         self.q = np.zeros(self.cols.shape[0], np.float32)
         self.q[:n_nodes] = np.asarray(q, np.float32)
 
         touched = np.unique(np.asarray(touched, np.int64))
         uu, vv, ww = _sym_halves(src, dst, weights)
         if not _fill_rows(self.cols, self.vals, touched, uu, vv, ww):
-            # a touched row outgrew kmax — cold rebuild fallback
-            it = self.rebuild(n_nodes, src, dst, weights, q)
+            # a touched row outgrew kmax — rebuild wider rows, same field
+            it = self.rebuild(n_nodes, src, dst, weights, q, heat0=self.heat[:n_nodes].copy())
             return WarmStats(len(touched), 0, 0, it, self.residual)
         self.alpha = self._effective_alpha()
         self._sync_device(rows=touched)
@@ -321,11 +449,12 @@ class StreamingHeat:
             and len(frontier)
         ):
             sub = np.concatenate([frontier, np.where(bmask)[0], np.where(cmask)[0]])
-            # pad the subproblem coarsely (1024-row quantum): sub sizes vary
-            # per batch, and every new shape is a fresh while_loop compile —
-            # coarse buckets make consecutive batches reuse the same one
-            # (pad rows = isolated, clamped to 0)
-            n_sub = max(1024, int(np.ceil(len(sub) / 1024.0)) * 1024)
+            # pad the subproblem to a power of two below the field's rows, or
+            # to the field's: sub sizes vary per batch, and the buckets let
+            # consecutive batches reuse one program (pad rows = isolated,
+            # held at 0)
+            n_sub = _pow2(len(sub), _ROW_MIN)
+            n_sub = n_sub if n_sub < n_pad else n_pad
             lmap = np.full(n_pad, -1, dtype=np.int64)
             lmap[sub] = np.arange(len(sub))
             rows_fb = sub[: len(frontier) + int(bmask.sum())]
@@ -335,29 +464,19 @@ class StreamingHeat:
             vals_l = np.zeros((n_sub, self.cols.shape[1]), np.float32)
             cols_l[: len(rows_fb)] = lmap[self.cols[rows_fb]].astype(np.int32)
             vals_l[: len(rows_fb)] = self.vals[rows_fb]
-            clamp = jnp.arange(len(frontier), n_sub)
-            clamp_np = np.zeros(n_sub - len(frontier), np.float32)
-            clamp_np[: len(sub) - len(frontier)] = self.heat[sub[len(frontier):]]
-            clamp_vals = jnp.asarray(clamp_np)
-            q_np = np.zeros(n_sub, np.float32)
-            q_np[: len(sub)] = self.q[sub]
-            q_sub = jnp.asarray(q_np)
-            h_np = np.zeros(n_sub, np.float32)
-            h_np[: len(sub)] = self.heat[sub]
-            cols_j, vals_j = jnp.asarray(cols_l), jnp.asarray(vals_l)
-            h_sub, k_local = steady_state(
-                jnp.asarray(h_np),
-                lambda hh, qq: self._sweep(hh, cols_j, vals_j, qq)
-                .at[clamp].set(clamp_vals),
-                lambda k: q_sub,
-                max_iters=local_iters,
-                tol=self.tol,
-            )
+            # every row but the frontier's is held at its present heat
+            frozen = np.arange(n_sub) >= len(frontier)
+            q_sub = np.zeros(n_sub, np.float32)
+            q_sub[: len(sub)] = self.q[sub]
+            h_sub = np.zeros(n_sub, np.float32)
+            h_sub[: len(sub)] = self.heat[sub]
+            h_sub, k_local, _ = self._relax(h_sub, cols_l, vals_l, q_sub, frozen, h_sub,
+                                            max_iters=local_iters)
             local_done = int(k_local)
             self.heat[frontier] = np.asarray(h_sub)[: len(frontier)]
 
         # --- global mop-up sweeps ----------------------------------------
-        it = self.solve()
+        it = self.solve(local_iters=local_done)
         return WarmStats(
             frontier_size=len(frontier),
             halo_size=0 if bmask is None else int(bmask.sum() + cmask.sum()),
