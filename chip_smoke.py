@@ -15,12 +15,13 @@ c. serve — batches of 256 and 1024 requests through ``serve_batch`` and a
    ``latency_s`` to its f32 byte sums) and bit for bit against the
    kernel-free numpy batch router;
 d. churn — one 1% churn batch through ``apply_updates`` (warm DHD on
-   ``kernels.ops.dhd_step``) and ``flush_migrations()``, then serving
+   ``kernels.ops.dhd_sliced``) and ``flush_migrations()``, then serving
    checked again;
 e. dispatch — the ``kernels.dispatch{op,path}`` counters must show
-   ``route_expand``, ``diffuse_batch`` and ``dhd_step`` on the Pallas kernel
-   and never on the reference; then the churned store's DHD step is checked
-   against ``kernels.ref.dhd_ell_ref`` on the device.
+   ``route_expand``, ``diffuse_batch`` and ``dhd_sliced`` on the Pallas
+   kernel and never on the reference; then the churned store's DHD step
+   over its sliced ELL is checked against ``kernels.ref.dhd_ell_ref`` over
+   its dense ELL on the device.
 
 ``--chips 4`` runs ``ShardedGeoGraphStore`` over four TPU devices against a
 single-process ``GeoGraphStore`` of the same build: identical ``serve_batch``
@@ -63,7 +64,7 @@ from repro.streaming import DeltaGraph, random_churn_batch  # noqa: E402
 STORE = dict(n_vertices=104_000, n_patterns=1024, hops=5, branch=2)
 # the bench_sharded store: 8-DC synthetic environment, 4 shards
 SHARDED = dict(n_vertices=12_000, n_patterns=240, n_dcs=8, n_shards=4)
-CHECKED_OPS = ("route_expand", "diffuse_batch", "dhd_step")
+CHECKED_OPS = ("route_expand", "diffuse_batch", "dhd_sliced")
 
 
 def log(msg: str) -> None:
@@ -197,19 +198,22 @@ def churn_phase(store, rate: float = 0.01, seed: int = 0) -> dict:
 
 
 def dhd_check(store) -> float:
-    """The churned store's DHD step on the dispatched path against
-    ``ref.dhd_ell_ref`` on the same device, within f32 tolerance."""
+    """The churned store's DHD step on the dispatched path (its sliced ELL)
+    against ``ref.dhd_ell_ref`` over its dense ELL on the same device,
+    within f32 tolerance."""
     import jax.numpy as jnp
 
     from repro.kernels import ops, ref
+    from repro.streaming.delta_dhd import _cut_slabs
 
     heat = store._heat
     p = heat.params
-    args = (jnp.asarray(heat.heat), jnp.asarray(heat.cols),
-            jnp.asarray(heat.vals), jnp.asarray(heat.q))
+    h, q = jnp.asarray(heat.heat), jnp.asarray(heat.q)
+    cols, vals = jnp.asarray(heat.cols), jnp.asarray(heat.vals)
+    src, blk = heat._slab
     kw = dict(alpha=heat.alpha, gamma=p.gamma, beta=p.beta)
-    got = np.asarray(ops.dhd_step(*args, **kw))
-    want = np.asarray(ref.dhd_ell_ref(*args, **kw))
+    got = np.asarray(ops.dhd_sliced(h, *_cut_slabs(cols, vals, src), blk, q, **kw))
+    want = np.asarray(ref.dhd_ell_ref(h, cols, vals, q, **kw))
     if not np.isfinite(got).all():
         raise AssertionError("kernel DHD field is not finite")
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)

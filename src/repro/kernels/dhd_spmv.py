@@ -27,7 +27,16 @@ the kernel path.
 structure with a 2-D grid (batch × row-blocks); ``vals`` may be per-batch
 (``[B, n, kmax]``), which is how the placement arena diffuses every
 candidate's super-node topology in a single launch.  ``dhd_ell_step`` is
-the same kernels at B = 1.
+the same kernels at B = 1.  Placement's diffusion (``ops.diffuse_batch``),
+``ops.dhd_step``/``dhd_step_batch`` and the streaming frontier sub-solve
+keep this dense layout.
+
+``dhd_sliced_step`` runs the same per-row math over a *sliced* ELL: each
+block of ``SLICE_ROWS`` rows keeps only as many slots as its widest row
+needs, the blocks' ``[w_b, SLICE_ROWS]`` slabs laid end to end, so both
+gathers run over the slabs' slots alone.  The streaming warm solve's
+global sweeps use it over rows sorted by degree (``streaming.delta_dhd``'s
+solver order), where the sort makes neighbouring rows alike in width.
 
 The coefficients ``alpha``, ``gamma`` and ``beta`` are float32 operands, not
 compile-time constants: the flow kernel reads ``alpha`` from SMEM, so a
@@ -44,7 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["dhd_ell_step", "dhd_ell_step_batch"]
+__all__ = ["dhd_ell_step", "dhd_ell_step_batch", "dhd_sliced_step"]
 
 # cells (8-padded neighbour slots x row lanes) of one f32 tile: 256 KiB, so
 # the flow pass's three tiles double-buffered plus its temporaries stay a
@@ -96,19 +105,17 @@ def _tile(ref):
     return ref[0] if len(ref.shape) == 3 else ref[...]
 
 
-def _count_kernel(h_u_ref, h_nb_ref, vals_ref, nout_ref):
-    h_u = h_u_ref[0]  # [1, block_n]
-    out_mask = (_tile(vals_ref) > 0) & (h_u > h_nb_ref[0])
-    nout_ref[0] = out_mask.astype(jnp.float32).sum(axis=0, keepdims=True)
+def _out_count(h_u, h_nb, vals):
+    """Per-row count of strictly-lower-heat neighbours in a ``[slots, rows]``
+    tile: a ``[1, rows]`` partial |N^out|."""
+    return ((vals > 0) & (h_u > h_nb)).astype(jnp.float32).sum(axis=0, keepdims=True)
 
 
-def _flow_kernel(alpha_ref, h_u_ref, nout_u_ref, h_nb_ref, nout_nb_ref, vals_ref, delta_ref):
-    alpha = alpha_ref[0]
-    h_u = h_u_ref[0]  # [1, block_n]
-    nout_u = jnp.maximum(nout_u_ref[0], 1.0)
-    h_nb = h_nb_ref[0]  # [kmax, block_n]
-    nout_nb = jnp.maximum(nout_nb_ref[0], 1.0)
-    vals = _tile(vals_ref)
+def _net_inflow(alpha, h_u, nout_u, h_nb, nout_nb, vals):
+    """Per-row inflow less outflow over a ``[slots, rows]`` tile: a
+    ``[1, rows]`` partial delta (linear in the slots, so partial tiles add)."""
+    nout_u = jnp.maximum(nout_u, 1.0)
+    nout_nb = jnp.maximum(nout_nb, 1.0)
     out_mask = (vals > 0) & (h_u > h_nb)
     in_mask = (vals > 0) & (h_nb > h_u)
     outflow = (alpha / nout_u * vals * jnp.where(out_mask, h_u - h_nb, 0.0)).sum(
@@ -117,7 +124,16 @@ def _flow_kernel(alpha_ref, h_u_ref, nout_u_ref, h_nb_ref, nout_nb_ref, vals_ref
     inflow = (alpha / nout_nb * vals * jnp.where(in_mask, h_nb - h_u, 0.0)).sum(
         axis=0, keepdims=True
     )
-    delta_ref[0] = inflow - outflow
+    return inflow - outflow
+
+
+def _count_kernel(h_u_ref, h_nb_ref, vals_ref, nout_ref):
+    nout_ref[0] = _out_count(h_u_ref[0], h_nb_ref[0], _tile(vals_ref))
+
+
+def _flow_kernel(alpha_ref, h_u_ref, nout_u_ref, h_nb_ref, nout_nb_ref, vals_ref, delta_ref):
+    delta_ref[0] = _net_inflow(alpha_ref[0], h_u_ref[0], nout_u_ref[0], h_nb_ref[0],
+                               nout_nb_ref[0], _tile(vals_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -210,3 +226,100 @@ def dhd_ell_step(
         heat[None], cols, vals, q[None], alpha=alpha, gamma=gamma, beta=beta,
         block_n=block_n, interpret=interpret,
     )[0]
+
+
+# ------------------------------------------------------------- sliced ELL
+# Rows per block and neighbour slots per chunk of the sliced layout.
+SLICE_ROWS = 256
+SLICE_CHUNK = 8
+
+
+def _first_chunk(blk_ref):
+    """Whether this grid step opens its block's run of chunks."""
+    c = pl.program_id(0)
+    return (c == 0) | (blk_ref[c] != blk_ref[jnp.maximum(c - 1, 0)])
+
+
+def _count_sliced_kernel(blk_ref, h_u_ref, h_nb_ref, vals_ref, nout_ref):
+    @pl.when(_first_chunk(blk_ref))
+    def _():
+        nout_ref[...] = jnp.zeros_like(nout_ref)
+
+    nout_ref[...] += _out_count(h_u_ref[...], h_nb_ref[...], vals_ref[...])
+
+
+def _flow_sliced_kernel(blk_ref, alpha_ref, h_u_ref, nout_u_ref, h_nb_ref, nout_nb_ref,
+                        vals_ref, delta_ref):
+    @pl.when(_first_chunk(blk_ref))
+    def _():
+        delta_ref[...] = jnp.zeros_like(delta_ref)
+
+    delta_ref[...] += _net_inflow(alpha_ref[0], h_u_ref[...], nout_u_ref[...], h_nb_ref[...],
+                                  nout_nb_ref[...], vals_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dhd_sliced_step(
+    heat: jnp.ndarray,  # [n] float32, n a multiple of SLICE_ROWS
+    cols: jnp.ndarray,  # [S, SLICE_ROWS] int32 sliced ELL (pad = self, or 0 past the slabs)
+    vals: jnp.ndarray,  # [S, SLICE_ROWS] float32 (0 where padded)
+    blk: jnp.ndarray,  # [S // SLICE_CHUNK] int32 row block of each chunk, ascending
+    q: jnp.ndarray,  # [n] source heat
+    alpha=0.5,  # float32 operands, as in dhd_ell_step_batch
+    gamma=0.1,
+    beta=0.3,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """One DHD update over a sliced ELL: each block of ``SLICE_ROWS`` rows
+    keeps only as many neighbour slots as its widest row needs.
+
+    Block ``b`` is a ``[w_b, SLICE_ROWS]`` slab of ``cols``/``vals`` (slots
+    on sublanes, rows on lanes, as the dense kernels' tiles), the slabs laid
+    end to end in block order; ``blk`` names the block of each
+    ``SLICE_CHUNK``-slot chunk.  Chunks past the last slab belong to the
+    last block with zero weights, so they add nothing.  The two gathers
+    (``heat[cols]``, ``n_out[cols]``) run over the slabs' slots alone; the
+    kernels walk the chunks on a 1-D grid with the block ids prefetched to
+    SMEM, and each block's partial counts and flows add up in its resident
+    ``[1, SLICE_ROWS]`` output tile.  Tile shapes are fixed, so one program
+    serves every layout of the same ``[n]`` and ``[S, SLICE_ROWS]`` shapes.
+    Matches ``dhd_ell_step`` over the same rows up to float summation order.
+    """
+    n = heat.shape[0]
+    chunks = blk.shape[0]
+    h = heat.astype(jnp.float32)
+    h_row = h[None, :]  # [1, n]
+    h_nb = h[cols]  # [S, SLICE_ROWS]
+    row_spec = pl.BlockSpec((1, SLICE_ROWS), lambda c, b: (0, b[c]))
+    tile_spec = pl.BlockSpec((SLICE_CHUNK, SLICE_ROWS), lambda c, b: (c, 0))
+    row_shape = jax.ShapeDtypeStruct((1, n), jnp.float32)
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+    n_out = pl.pallas_call(
+        _count_sliced_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(chunks,),
+            in_specs=[row_spec, tile_spec, tile_spec], out_specs=row_spec),
+        out_shape=row_shape,
+        compiler_params=params,
+        interpret=interpret,
+        name="dhd_sliced_count",
+    )(blk, h_row, h_nb, vals)
+
+    alpha = jnp.reshape(jnp.asarray(alpha, jnp.float32), (1,))
+    delta = pl.pallas_call(
+        _flow_sliced_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(chunks,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), row_spec, row_spec, tile_spec,
+                      tile_spec, tile_spec],
+            out_specs=row_spec),
+        out_shape=row_shape,
+        compiler_params=params,
+        interpret=interpret,
+        name="dhd_sliced_flow",
+    )(blk, alpha, h_row, n_out, h_nb, n_out[0][cols], vals)
+
+    gamma = jnp.asarray(gamma, jnp.float32)
+    beta = jnp.asarray(beta, jnp.float32)
+    return (1.0 - gamma) * (heat + delta[0]) + beta * q

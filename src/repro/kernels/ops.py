@@ -18,7 +18,7 @@ import numpy as np
 from . import ref
 from ..obs import get_registry
 from .autotune import get_autotuner
-from .dhd_spmv import dhd_ell_step, dhd_ell_step_batch
+from .dhd_spmv import dhd_ell_step, dhd_ell_step_batch, dhd_sliced_step
 from .embedding_bag import embedding_bag as _embedding_bag_kernel
 from .flash_attention import flash_attention as _flash_attention_kernel
 from .route_expand import route_expand as _route_expand_kernel
@@ -27,6 +27,7 @@ __all__ = [
     "attention",
     "dhd_step",
     "dhd_step_batch",
+    "dhd_sliced",
     "diffuse_batch",
     "bag_lookup",
     "edge_cache_stats",
@@ -226,6 +227,32 @@ def dhd_step(
         return out
     out = ref.dhd_ell_ref(heat, cols, vals, q, alpha=alpha, gamma=gamma, beta=beta)
     _obs_dispatch("dhd_step", "ref")
+    return out
+
+
+def dhd_sliced(
+    heat: jnp.ndarray,  # [n]
+    cols: jnp.ndarray,  # [S, SLICE_ROWS] sliced ELL
+    vals: jnp.ndarray,  # [S, SLICE_ROWS]
+    blk: jnp.ndarray,  # [S // SLICE_CHUNK] row block of each chunk
+    q: jnp.ndarray,  # [n]
+    alpha: float = 0.5,
+    gamma: float = 0.1,
+    beta: float = 0.3,
+    use_kernel: Optional[bool] = None,
+) -> jnp.ndarray:
+    """DHD update over a sliced ELL (``dhd_spmv.dhd_sliced_step``: the
+    streaming warm solve's degree-sorted layout); the Pallas kernels on TPU,
+    the segment-sum reference otherwise."""
+    if use_kernel is None:
+        use_kernel = on_tpu()
+    if use_kernel:
+        out = dhd_sliced_step(heat, cols, vals, blk, q, alpha=alpha, gamma=gamma, beta=beta,
+                              interpret=not on_tpu())
+        _obs_dispatch("dhd_sliced", "kernel")
+        return out
+    out = ref.dhd_sliced_ref(heat, cols, vals, blk, q, alpha=alpha, gamma=gamma, beta=beta)
+    _obs_dispatch("dhd_sliced", "ref")
     return out
 
 

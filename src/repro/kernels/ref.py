@@ -15,6 +15,7 @@ __all__ = [
     "attention_ref",
     "dhd_ell_ref",
     "dhd_ell_ref_batch",
+    "dhd_sliced_ref",
     "embedding_bag_ref",
     "route_expand_ref",
 ]
@@ -115,6 +116,41 @@ def dhd_ell_ref_batch(
         alpha / n_out[:, cols] * vals_b * jnp.where(in_mask, h_nb - h_u, 0.0)
     ).sum(axis=-1)
     return (1.0 - gamma) * (heat + inflow - outflow) + beta * q
+
+
+def dhd_sliced_ref(
+    heat: jnp.ndarray,  # [n], n a multiple of R
+    cols: jnp.ndarray,  # [S, R] sliced ELL neighbour ids (slots x rows of a block)
+    vals: jnp.ndarray,  # [S, R] edge weights (0 where padded)
+    blk: jnp.ndarray,  # [C] row block of each chunk of S // C slots
+    q: jnp.ndarray,  # [n] source heat this step
+    alpha: float = 0.5,
+    gamma: float = 0.1,
+    beta: float = 0.3,
+) -> jnp.ndarray:
+    """DHD step (Eqs. 7-8) over the sliced ELL of
+    ``dhd_spmv.dhd_sliced_step``: slot ``[s, j]`` is an edge of row
+    ``blk[s // chunk] * R + j``.  Per-row sums add each chunk's slots, then
+    the chunks of a block (segment sums over ``blk``)."""
+    s, r = cols.shape
+    c = blk.shape[0]
+    hb = heat.reshape(-1, r)  # [n_blocks, R]
+    h_u = hb[blk][:, None, :]  # [C, 1, R]
+    h_nb = heat[cols].reshape(c, s // c, r)
+    w = vals.reshape(c, s // c, r)
+    active = w > 0
+    out_mask = active & (h_u > h_nb)
+    in_mask = active & (h_nb > h_u)
+
+    def per_row(x):  # [C, chunk, R] per slot -> [n] per row
+        return jax.ops.segment_sum(x.sum(axis=1), blk, num_segments=hb.shape[0]).reshape(-1)
+
+    n_out = jnp.maximum(per_row(out_mask.astype(heat.dtype)), 1)
+    nout_u = n_out.reshape(-1, r)[blk][:, None, :]
+    nout_nb = n_out[cols].reshape(c, s // c, r)
+    outflow = alpha / nout_u * w * jnp.where(out_mask, h_u - h_nb, 0.0)
+    inflow = alpha / nout_nb * w * jnp.where(in_mask, h_nb - h_u, 0.0)
+    return (1.0 - gamma) * (heat + per_row(inflow - outflow)) + beta * q
 
 
 def route_expand_masks(

@@ -35,7 +35,7 @@ def kernels_interpreted(monkeypatch):
     from repro.kernels import autotune, ops
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    for name in ("dhd_ell_step", "dhd_ell_step_batch", "_route_expand_kernel"):
+    for name in ("dhd_ell_step", "dhd_ell_step_batch", "dhd_sliced_step", "_route_expand_kernel"):
         monkeypatch.setattr(
             ops, name, functools.partial(_interpreted, getattr(ops, name))
         )
@@ -58,9 +58,9 @@ def test_dispatch_check_rejects_reference_path():
     reg = MetricsRegistry(enabled=True)
     for op in chip_smoke.CHECKED_OPS:
         reg.counter("kernels.dispatch", op=op, path="kernel").inc()
-    assert chip_smoke.dispatch_counts(reg)["dhd_step"]["kernel"] == 1
-    reg.counter("kernels.dispatch", op="dhd_step", path="ref").inc()
-    with pytest.raises(AssertionError, match="dhd_step"):
+    assert chip_smoke.dispatch_counts(reg)["dhd_sliced"]["kernel"] == 1
+    reg.counter("kernels.dispatch", op="dhd_sliced", path="ref").inc()
+    with pytest.raises(AssertionError, match="dhd_sliced"):
         chip_smoke.dispatch_counts(reg)
 
 

@@ -7,9 +7,10 @@ import pytest
 from repro.core.dhd import dhd_step_edges
 from repro.core.graph import build_csr, build_ell
 from repro.kernels import ops, ref
-from repro.kernels.dhd_spmv import dhd_ell_step, dhd_ell_step_batch
+from repro.kernels.dhd_spmv import dhd_ell_step, dhd_ell_step_batch, dhd_sliced_step
 from repro.kernels.embedding_bag import embedding_bag
 from repro.kernels.flash_attention import flash_attention
+from repro.streaming.delta_dhd import _cut_slabs, _k_bucket, _slab_index
 
 ATTN_SWEEP = [
     # b, hq, hkv, sq, skv, d, causal, window, dtype
@@ -116,6 +117,52 @@ def test_dhd_kernel_batch_matches_ref(n, kmax, block_n, B, batched_vals):
         np.testing.assert_allclose(
             np.asarray(out[k]), np.asarray(single), atol=1e-5, rtol=1e-4
         )
+
+
+def _ell_with_degrees(rng, deg, kmax):
+    """A dense ELL whose row ``u`` holds ``deg[u]`` random edges (pad = self)."""
+    n = len(deg)
+    cols = np.repeat(np.arange(n, dtype=np.int32)[:, None], kmax, axis=1)
+    vals = np.zeros((n, kmax), np.float32)
+    for u, d in enumerate(deg):
+        cols[u, :d] = rng.integers(0, n, d)
+        vals[u, :d] = rng.random(d) + 0.05
+    return cols, vals
+
+
+@pytest.mark.parametrize("n_real,top,headroom,extra_rows", [
+    (600, 23, 8, 0),  # pad rows fill the last block's tail
+    (512, 23, 8, 8),  # the last block holds only pad rows: 8 slots wide
+    (768, 24, 0, 24),  # the widest row exactly fills its block; capacity past the slabs
+])
+def test_dhd_sliced_kernel_matches_dense(n_real, top, headroom, extra_rows):
+    """The sliced kernels (interpreted) give the dense kernels' and both
+    references' step over the same rows, within 1e-6 relative."""
+    rng = np.random.default_rng(7)
+    n, kmax = 768, 32
+    deg = np.zeros(n, np.int64)
+    deg[:n_real] = np.sort(rng.integers(1, top + 1, n_real))[::-1]
+    deg[0] = top
+    widths = np.array([_k_bucket(d + headroom) for d in deg.reshape(-1, 256).max(axis=1)])
+    assert (widths >= deg.reshape(-1, 256).max(axis=1)).all()
+    if n_real == 512:
+        assert widths[-1] == 8
+    if headroom == 0:
+        assert deg[0] == widths[0]
+    cols, vals = _ell_with_degrees(rng, deg, kmax)
+    src, blk = _slab_index(widths, kmax, int(widths.sum()) + extra_rows)
+    s_cols, s_vals = _cut_slabs(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(src))
+    heat = jnp.asarray(rng.random(n), jnp.float32)
+    q = jnp.asarray(rng.random(n) * 0.1, jnp.float32)
+    out = np.asarray(dhd_sliced_step(heat, s_cols, s_vals, blk, q, alpha=np.float32(0.05),
+                                     interpret=True))
+    wants = [
+        dhd_ell_step(heat, cols, vals, q, alpha=np.float32(0.05), interpret=True),
+        ref.dhd_ell_ref(heat, jnp.asarray(cols), jnp.asarray(vals), q, alpha=0.05),
+        ref.dhd_sliced_ref(heat, s_cols, s_vals, jnp.asarray(blk), q, alpha=0.05),
+    ]
+    for want in map(np.asarray, wants):
+        assert np.abs(out - want).max() <= 1e-6 * np.abs(want).max()
 
 
 def test_dhd_tail_edge_cache_reused():

@@ -18,8 +18,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.routing import RouteFastConfig
 from repro.kernels import ops
-from repro.kernels.dhd_spmv import dhd_ell_step, dhd_ell_step_batch
+from repro.kernels.dhd_spmv import dhd_ell_step, dhd_ell_step_batch, dhd_sliced_step
 from repro.kernels.route_expand import route_expand
+from repro.streaming import delta_dhd
 
 
 @pytest.fixture(scope="module")
@@ -114,3 +115,31 @@ def test_dhd_ell_step_batch_compiles_for_v5e(one_chip, B, n, kmax, batched_vals)
     txt = _compile(fn, one_chip, ((B, n), f32), ((n, kmax), jnp.int32),
                    (vals, f32), ((B, n), f32))
     assert _kernels(txt) == ["dhd_ell_count", "dhd_ell_flow"]
+
+
+def test_dhd_sliced_step_compiles_for_v5e(one_chip):
+    """The sliced kernels at the SF10 warm solve's size: 69,632 rows over
+    18,432 slot rows."""
+    n, slot_rows = 69_632, 18_432
+    f32, i32 = jnp.float32, jnp.int32
+    fn = functools.partial(dhd_sliced_step, interpret=False)
+    txt = _compile(fn, one_chip, ((n,), f32), ((slot_rows, 256), i32),
+                   ((slot_rows, 256), f32), ((slot_rows // 8,), i32), ((n,), f32))
+    assert _kernels(txt) == ["dhd_sliced_count", "dhd_sliced_flow"]
+
+
+def test_warm_sliced_relax_compiles_for_v5e(one_chip, monkeypatch):
+    """The warm solve's global relaxation as ``StreamingHeat`` compiles it on
+    a TPU: the module's name still holds ``heat_relax`` (what the trace
+    readers match on) and it runs the sliced kernels."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # the kernels, not the interpreter
+    n, kmax, slot_rows = 69_632, 112, 18_432
+    f32, i32 = jnp.float32, jnp.int32
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in [
+        ((n,), f32), ((n, kmax), i32), ((n, kmax), f32), ((slot_rows,), i32),
+        ((slot_rows // 8,), i32), ((n,), f32), ((n,), jnp.bool_), ((n,), f32), ((3,), f32),
+        ((), f32), ((), i32)]]
+    txt = jax.jit(delta_dhd._heat_relax_sliced, static_argnames="use_kernel").lower(
+        *args, use_kernel=True).compile().as_text()
+    assert "heat_relax" in re.match(r"HloModule (\S+?),", txt).group(1)
+    assert set(_kernels(txt)) == {"dhd_sliced_count", "dhd_sliced_flow"}
